@@ -16,6 +16,8 @@ import traceback
 
 
 def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
     from . import async_bench, engine_scale, fig3_selection, fig4_cep, fig7_cardinality, inclusion, kernels, regret, roofline, scenarios_bench, serve_chaos, serve_front, table_training
 
     ap = argparse.ArgumentParser(description=__doc__)
@@ -23,6 +25,7 @@ def main(argv=None) -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="force the reduced CI protocol (overrides REPRO_BENCH_QUICK)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     quick = args.smoke or os.environ.get("REPRO_BENCH_QUICK", "1") == "1"
     benches = {

@@ -177,6 +177,7 @@ class _LocalCtx:
             # fused engines consume identical noise (bit-identity contract)
             allocator = getattr(fl, "allocator", "sort")
             quota_fn = program.quota_fn
+            tile = program.tile
 
             def select(state, rng):
                 sigma = quota_fn(state.t)
@@ -187,13 +188,13 @@ class _LocalCtx:
                         scalars = masked_prob_alloc_scalars(w, k, sigma)
                     with stage("round.sample"):
                         p, capped, _, idx = fused_alloc_select(
-                            w, g, k, sigma=sigma, scalars=scalars
+                            w, g, k, sigma=sigma, scalars=scalars, tile=tile
                         )
                 else:
                     with stage("round.allocate"):
                         p, capped = e3cs_probs(state.e3cs, k, sigma)
                     with stage("round.sample"):
-                        _, idx = fused_perturb_select(p, g, k)
+                        _, idx = fused_perturb_select(p, g, k, tile=tile)
                 return idx, p, capped, sigma, selection_mask(idx, K)
 
         else:
@@ -255,7 +256,8 @@ class _ShardCtx:
                         )
                     with stage("round.sample"):
                         p, capped, vals, loc = fused_alloc_select(
-                            w, g, k, sigma=sigma, scalars=scalars, active=active_loc
+                            w, g, k, sigma=sigma, scalars=scalars, active=active_loc,
+                            tile=program.tile,
                         )
                         gi = loc + jnp.asarray(d * Ks, jnp.int32)
                         cv = jax.lax.all_gather(vals, axis_name, tiled=True)
@@ -432,7 +434,7 @@ def _make_step(program: "RoundProgram", ctx, lean: bool, taps: bool = False,
                     rings[0] if (not sync and S > 0) else None,
                     rings[1] if late_fb else None,
                     kind=kind, residual=residual, eta=eta, K_glob=K_glob,
-                    decay=decay, active=ctx.active,
+                    decay=decay, active=ctx.active, tile=program.tile,
                 )
                 x = tail["x"]
                 logw = tail["logw_pre"] - ctx.pmax(tail["m"])

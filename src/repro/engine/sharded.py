@@ -53,11 +53,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at the top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pinned 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
 from repro.core.selection.sampling import local_topk_candidates, merge_topk_candidates, perturbed_scores
 from repro.kernels.bisect_tiles import bisect_block_sums
 
@@ -89,10 +84,7 @@ def _shmap(f, mesh, in_specs, out_specs):
     """`shard_map` with replication checking off: the bisection's `fori_loop`
     carry trips the static replication checker (jax#21296); the specs here are
     explicit so the check adds nothing."""
-    try:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-    except TypeError:  # newer jax renamed the kwarg
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
 def _axis_size(mesh, axis_name: str) -> int:

@@ -44,22 +44,21 @@ def interpret_mode() -> Optional[bool]:
     raise ValueError(f"{_ENV}={raw!r}: expected 1/0/auto")
 
 
-def kernel_route(cpu_kernel_default: bool = True, tpu_kernel: bool = True) -> Tuple[bool, bool]:
+def kernel_route(cpu_kernel_default: bool = True) -> Tuple[bool, bool]:
     """Resolve ``(use_kernel, interpret)`` for one kernel call.
 
     ``cpu_kernel_default`` is the auto-mode CPU behaviour: True runs the
     kernel through the Pallas interpreter (cheap ops where the interpreter
     is fine), False uses the jnp reference (hot paths where the interpreter
-    is too slow).  ``tpu_kernel=False`` opts a site out of the compiled
-    kernel even on TPU (e.g. unsupported dtype); ``REPRO_INTERPRET=1``
-    still forces the kernel, in interpret mode.
+    is too slow).  On TPU the compiled kernel always runs unless
+    ``REPRO_INTERPRET=0`` asks for the reference.
     """
     mode = interpret_mode()
     if mode is False:
         return False, False
     backend = jax.default_backend()
     if mode is True:
-        return True, backend != "tpu" or not tpu_kernel
+        return True, backend != "tpu"
     if backend == "tpu":
-        return tpu_kernel, False
+        return True, False
     return cpu_kernel_default, True

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .gumbel_topk import NEG_INF, streaming_topk_body
+from .gumbel_topk import NEG_INF, sort_topk, streaming_topk_body, topk_buffers, topk_tile
 
 __all__ = ["fused_gumbel_topk_kernel_call", "e3cs_update_kernel_call"]
 
@@ -62,13 +62,14 @@ def fused_gumbel_topk_kernel_call(p: jax.Array, u: jax.Array, k: int, tile: int 
     Returns (values, indices): top-k perturbed scores, descending.
     """
     K = p.shape[0]
-    tile = min(tile, max(K, 8))
+    tile = topk_tile(tile, K)
     K_p = math.ceil(K / tile) * tile
     if K_p != K:
         p = jnp.pad(p, (0, K_p - K))
         u = jnp.pad(u, (0, K_p - K), constant_values=0.5)
     n_tiles = K_p // tile
     kernel = functools.partial(_fused_kernel, k=k, tile=tile, n_tiles=n_tiles, K=K)
+    out_specs, out_shape, scratch = topk_buffers(k)
     vals, idx = pl.pallas_call(
         kernel,
         grid=(n_tiles,),
@@ -76,18 +77,12 @@ def fused_gumbel_topk_kernel_call(p: jax.Array, u: jax.Array, k: int, tile: int 
             pl.BlockSpec((tile,), lambda t: (t,)),
             pl.BlockSpec((tile,), lambda t: (t,)),
         ],
-        out_specs=[
-            pl.BlockSpec((k,), lambda t: (0,)),
-            pl.BlockSpec((k,), lambda t: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k,), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((k,), jnp.float32), pltpu.VMEM((k,), jnp.int32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
     )(p, u)
-    return vals, idx
+    return sort_topk(vals, idx, k)
 
 
 def _update_kernel(logw_ref, p_ref, mask_ref, x_ref, frozen_ref, scale_ref, out_ref, tmax_ref, *, tile, K):

@@ -20,11 +20,12 @@ once:
 * **tail** (``fused_round_tail``) — per tile: unpack the packed 1-bit /
   2-bit trace row (or pass dense outcomes through), derive the on-time bits,
   apply Eq. 16/17's clamped importance-weighted log-weight step with the
-  overflow/activity freeze, emit the per-tile re-centering max, refresh the
-  pow-d loss cache, and pop/shift/push both staleness rings (late credit +
-  late feedback) — everything downstream of the outcome row except the
-  global re-centering, which needs a cross-tile (and cross-shard) max and is
-  finished by the caller from the (n_tiles,) partial maxes.
+  overflow/activity freeze, emit the per-tile re-centering max (one
+  lane-dense 1024-wide block per tile), refresh the pow-d loss cache, and
+  pop/shift/push both staleness rings (late credit + late feedback) —
+  everything downstream of the outcome row except the global re-centering,
+  which needs a cross-tile (and cross-shard) max and is finished by the
+  caller from the partial maxes.
 
 Bit-identity contract: with dispatch on the jnp references (``ref.py``) the
 fused engine path is staged-op-for-staged-op identical by construction; in
@@ -54,8 +55,9 @@ from repro.core.volatility import DEAD_LAG
 
 from .autotune import best_config
 from .dispatch import kernel_route
-from .gumbel_topk import NEG_INF, streaming_topk_body
+from .gumbel_topk import LANE_TILE, NEG_INF, sort_topk, streaming_topk_body, topk_buffers, topk_tile
 from .ref import fused_alloc_select_ref, fused_perturb_select_ref, round_tail_ref
+from .unpack_bits import BYTE_TILE, expand_packed
 
 __all__ = [
     "fused_alloc_select",
@@ -123,7 +125,7 @@ def fused_select_kernel_call(
     Returns ``(p, capped_f32, vals, idx)`` or ``(vals, idx)``."""
     from_w = scalars is not None
     K = w.shape[0]
-    tile = min(tile, max(K, 8))
+    tile = topk_tile(tile, K)
     K_p = math.ceil(K / tile) * tile
     pad = K_p - K
     if pad:
@@ -160,11 +162,9 @@ def fused_select_kernel_call(
             jax.ShapeDtypeStruct((K_p,), jnp.float32),
             jax.ShapeDtypeStruct((K_p,), jnp.float32),
         ]
-    out_specs += [pl.BlockSpec((k,), lambda t: (0,)), pl.BlockSpec((k,), lambda t: (0,))]
-    out_shape += [
-        jax.ShapeDtypeStruct((k,), jnp.float32),
-        jax.ShapeDtypeStruct((k,), jnp.int32),
-    ]
+    topk_specs, topk_shape, scratch = topk_buffers(k)
+    out_specs += topk_specs
+    out_shape += topk_shape
     kernel = functools.partial(
         _select_kernel, k=k, tile=tile, n_tiles=n_tiles, K=K, has_active=has_active, from_w=from_w
     )
@@ -174,14 +174,13 @@ def fused_select_kernel_call(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((k,), jnp.float32), pltpu.VMEM((k,), jnp.int32)],
+        scratch_shapes=scratch,
         interpret=interpret,
     )(*args)
     if from_w:
         p, c, vals, idx = out
-        return p[:K], c[:K], vals, idx
-    vals, idx = out
-    return vals, idx
+        return (p[:K], c[:K], *sort_topk(vals, idx, k))
+    return sort_topk(*out, k)
 
 
 def fused_alloc_select(
@@ -266,16 +265,12 @@ def _make_tail_kernel(*, kind, S, late_fb, has_active, eta, K_glob, K, tile, dec
             fb_out = refs.pop(0)
 
         ti = pl.program_id(0)
-        # -- decode the outcome row (same integer ops as unpack_bits/_crumbs)
+        # -- decode the outcome row (the unpack_bits / unpack_crumbs expansion)
         lag = None
         if kind == "bits":
-            b = obs.astype(jnp.int32)  # (tile//8,)
-            shifts = jax.lax.broadcasted_iota(jnp.int32, (tile // 8, 8), 1)
-            x = (jnp.right_shift(b[:, None], shifts) & 1).reshape(tile).astype(jnp.float32)
+            x = expand_packed(obs.astype(jnp.int32), 8).astype(jnp.float32)
         elif kind == "crumbs":
-            b = obs.astype(jnp.int32)  # (tile//4,)
-            shifts = jax.lax.broadcasted_iota(jnp.int32, (tile // 4, 4), 1) * 2
-            codes = (jnp.right_shift(b[:, None], shifts) & 3).reshape(tile)
+            codes = expand_packed(obs.astype(jnp.int32), 4)
             lag = jnp.where(codes == _LAG_DEAD_CODE, DEAD_LAG, codes)
         elif kind == "x":
             x = obs
@@ -298,7 +293,8 @@ def _make_tail_kernel(*, kind, S, late_fb, has_active, eta, K_glob, K, tile, dec
         valid = pos < K
         if act is not None:
             valid = valid & (act > 0)
-        tmax_ref[0] = jnp.max(jnp.where(valid, logw_pre, -jnp.inf))
+        # per-tile max, broadcast over one lane-dense block
+        tmax_ref[...] = jnp.full((LANE_TILE,), jnp.max(jnp.where(valid, logw_pre, -jnp.inf)))
         loss_ref[...] = jnp.where(mask > 0, 1.0 - x, loss)
 
         # -- staleness rings: pop slot 0, shift, push this round's schedule
@@ -344,8 +340,9 @@ def round_tail_kernel_call(
     is_async = kind in ("crumbs", "lag")
     S = len(decay) if credit is not None else 0
     late_fb = fb is not None
-    tile = min(tile, max(K, 8))
-    tile = max(8, tile - tile % 8)  # packed rows decode 8 (bits) / 4 (crumbs) per byte
+    # whole byte tiles for the packed decode, whole 128-lane rows otherwise
+    granule = {"bits": 8 * BYTE_TILE, "crumbs": 4 * BYTE_TILE}.get(kind, LANE_TILE)
+    tile = -(-min(tile, K) // granule) * granule
     K_p = math.ceil(K / tile) * tile
     pad = K_p - K
     has_active = active is not None
@@ -396,10 +393,10 @@ def round_tail_kernel_call(
     if is_async:
         out_specs.append(vec)
         out_shape.append(jax.ShapeDtypeStruct((K_p,), jnp.int32))  # lag
-    out_specs += [vec, pl.BlockSpec((1,), lambda t: (t,)), vec]
+    out_specs += [vec, pl.BlockSpec((LANE_TILE,), lambda t: (t,)), vec]
     out_shape += [
         jax.ShapeDtypeStruct((K_p,), jnp.float32),  # logw_pre
-        jax.ShapeDtypeStruct((n_tiles,), jnp.float32),  # per-tile masked max
+        jax.ShapeDtypeStruct((n_tiles * LANE_TILE,), jnp.float32),  # per-tile masked max
         jax.ShapeDtypeStruct((K_p,), jnp.float32),  # loss_cache
     ]
     if S > 0:

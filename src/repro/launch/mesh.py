@@ -35,16 +35,20 @@ def make_host_mesh(D: int | None = None, axis_name: str = "shards"):
         XLA_FLAGS=--xla_force_host_platform_device_count=8
 
     (the CI sharded smoke and ``tests/conftest.py`` both set this).  Raises
-    with that hint when fewer than D devices exist — the flag cannot be
-    applied retroactively from here.
+    when fewer than D devices exist: with that hint on a CPU host (the flag
+    cannot be applied retroactively from here), with the chip count on an
+    accelerator host.
     """
     devs = jax.devices()
     D = len(devs) if D is None else int(D)
     if D < 1 or D > len(devs):
-        raise RuntimeError(
-            f"need {D} devices for the {axis_name!r} mesh but jax sees {len(devs)}; on a CPU host "
-            f"set XLA_FLAGS=--xla_force_host_platform_device_count={max(D, 2)} before the process starts"
-        )
+        platform = devs[0].platform
+        if platform == "cpu":
+            hint = (f"on a CPU host set XLA_FLAGS=--xla_force_host_platform_device_count={max(D, 2)} "
+                    "before the process starts")
+        else:
+            hint = f"this {platform} host has {len(devs)} chip(s); run on a host with at least {D}"
+        raise RuntimeError(f"need {D} devices for the {axis_name!r} mesh but jax sees {len(devs)}; {hint}")
     return jax.sharding.Mesh(np.asarray(devs[:D]), (axis_name,))
 
 

@@ -48,6 +48,7 @@ import numpy as np
 from repro.core.volatility import BernoulliVolatility, BinaryLag, CompletionLag, paper_success_rates
 from repro.engine.multi_job import make_multi_job, multi_job_init, pack_jobs
 from repro.engine.round_program import staleness_ring_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import ROUND_TAPS, Reporter, SketchSpec, SpanTimer
 
 __all__ = ["run_service", "run_service_compiled", "run_service_sharded", "run_server", "main"]
@@ -521,6 +522,7 @@ def main():
                          "horizon completes through it")
     ap.add_argument("--smoke", action="store_true", help="tiny CPU-friendly run")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.jobs, args.rounds = 4, 10
     K_max = args.clients or (512 if args.smoke else 4096)

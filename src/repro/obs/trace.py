@@ -2,17 +2,16 @@
 
 Two instruments, one per side of the dispatch boundary:
 
-* ``stage(name)`` — a trace annotation for *device* code.  Inside traced
-  jax code it is ``jax.named_scope``: zero runtime cost, the stage name
-  lands in HLO op metadata so ``jax.profiler`` traces (and XLA dumps) show
-  allocate / select / observe / credit / update as named regions of the
-  round.  Outside a trace it still works as a plain context manager, and on
-  the host thread it additionally opens a ``jax.profiler.TraceAnnotation``
-  so host-side profiler timelines pick the span up too.
+* ``stage(name)`` — a trace annotation for *device* code: a
+  ``jax.named_scope``, zero runtime cost; the stage name lands in HLO op
+  metadata so ``jax.profiler`` traces (and XLA dumps) show allocate /
+  select / observe / credit / update as named regions of the round.
 * ``SpanTimer`` — a wall-clock span timer for *host* code (the serving
   loop): each ``span(name)`` context feeds a ``LatencyHistogram``, giving
   real p50/p99 latency from bucketed counts — O(n_buckets) memory, never
-  per-request storage.
+  per-request storage.  ``annotate=True`` also opens a
+  ``jax.profiler.TraceAnnotation``, so the span shows on the profiler's
+  host timeline.
 
 ``LatencyHistogram`` buckets are log-spaced between ``lo`` and ``hi``
 seconds; quantiles interpolate within the winning bucket on cumulative
@@ -25,6 +24,7 @@ import contextlib
 import time
 from typing import Dict, Optional
 
+import jax
 import numpy as np
 
 __all__ = ["stage", "SpanTimer", "LatencyHistogram"]
@@ -32,37 +32,15 @@ __all__ = ["stage", "SpanTimer", "LatencyHistogram"]
 
 @contextlib.contextmanager
 def stage(name: str):
-    """Annotate a named pipeline stage on whichever side we are running.
+    """Name a pipeline stage of device code: a ``jax.named_scope``.
 
-    Under ``jax.jit`` tracing this scopes op names (free at runtime); on the
-    host it also opens a profiler TraceAnnotation so spans appear in
-    ``jax.profiler`` timelines.  Degrades to a no-op context if jax is
-    missing or its profiler API moved.
+    Under ``jax.jit`` tracing the stage name lands in the ops' metadata
+    (free at run time), so profiler traces and XLA dumps show the round's
+    stages as named regions; outside a trace it is a cheap push/pop on
+    jax's name stack.  Host spans use ``SpanTimer.span(annotate=True)``,
+    which opens a profiler ``TraceAnnotation`` instead.
     """
-    try:
-        import jax
-
-        on_host = True
-        try:
-            on_host = jax.core.trace_state_clean()
-        except Exception:
-            pass
-        # named_scope is always safe: inside a trace it names ops, outside it
-        # is a cheap push/pop on jax's name stack.
-        with jax.named_scope(name):
-            ann = None
-            if on_host:
-                try:
-                    ann = jax.profiler.TraceAnnotation(name)
-                    ann.__enter__()
-                except Exception:
-                    ann = None
-            try:
-                yield
-            finally:
-                if ann is not None:
-                    ann.__exit__(None, None, None)
-    except ImportError:
+    with jax.named_scope(name):
         yield
 
 
@@ -161,9 +139,11 @@ class SpanTimer:
     @contextlib.contextmanager
     def span(self, name: str, annotate: bool = False):
         """Time a block into the ``name`` histogram; with ``annotate`` the
-        span also lands in profiler timelines via ``stage``."""
+        span also lands in the profiler's host timeline."""
         h = self.get(name)
-        ctx: contextlib.AbstractContextManager = stage(name) if annotate else contextlib.nullcontext()
+        ctx: contextlib.AbstractContextManager = (
+            jax.profiler.TraceAnnotation(name) if annotate else contextlib.nullcontext()
+        )
         t0 = time.perf_counter()
         with ctx:
             yield

@@ -10,7 +10,7 @@ from repro.kernels.gumbel_topk import gumbel_topk_kernel_call
 RNG = np.random.default_rng(0)
 
 
-@pytest.mark.parametrize("K,k,tile", [(100, 10, 32), (1000, 20, 256), (513, 7, 128)])
+@pytest.mark.parametrize("K,k,tile", [(100, 10, 32), (1000, 20, 256), (513, 7, 128), (2600, 30, 1024)])
 def test_gumbel_topk_matches_lax_topk(K, k, tile):
     scores = jnp.asarray(RNG.normal(size=(K,)), jnp.float32)
     vals, idx = gumbel_topk_kernel_call(scores, k, tile=tile, interpret=True)

@@ -293,6 +293,36 @@ def test_round_tail_kernel_matches_ref_ragged(kind, S, late_fb, with_active):
         )
 
 
+@pytest.mark.parametrize("with_active", [False, True])
+def test_select_kernel_multi_tile_matches_ref(with_active):
+    # three 1024-score tiles, the last one ragged: the cross-tile merge
+    w, g, kk, sigma, scalars, active = _select_inputs(n=2 * 1024 + RAGGED_K, kk=40, with_active=with_active)
+    pr, cr, vr, ir = ref.fused_alloc_select_ref(w, g, kk, sigma=sigma, scalars=scalars, active=active)
+    pk, ck, vk, ik = fused_alloc_select(
+        w, g, kk, sigma=sigma, scalars=scalars, active=active, tile=1024, interpret=True
+    )
+    np.testing.assert_array_equal(np.asarray(pk), np.asarray(pr))
+    np.testing.assert_array_equal(np.asarray(ik), np.asarray(ir))
+    np.testing.assert_array_equal(np.asarray(vk), np.asarray(vr))
+
+
+@pytest.mark.parametrize("kind,S,late_fb,with_active", TAIL_CASES,
+                         ids=[f"{c[0]}-S{c[1]}{'-fb' if c[2] else ''}{'-act' if c[3] else ''}"
+                              for c in TAIL_CASES])
+def test_round_tail_kernel_multi_tile_matches_ref(kind, S, late_fb, with_active):
+    # three 8192-client tiles, the last one ragged: whole-byte-tile decode
+    # across tile boundaries and the per-tile max blocks
+    sync = kind in ("bits", "x")
+    args, kw = _tail_inputs(n=2 * 8192 + RAGGED_K, kind=kind, S=0 if sync else S,
+                            with_active=with_active, late_fb=late_fb)
+    want = ref.round_tail_ref(*args, **kw)
+    got = fused_round_tail(*args, **kw, tile=8192, interpret=True)
+    for key in want:
+        np.testing.assert_array_equal(
+            np.asarray(got[key]), np.asarray(want[key]), err_msg=f"tail product {key!r}"
+        )
+
+
 def test_round_tail_tile_invariant():
     args, kw = _tail_inputs(kind="crumbs", S=2, late_fb=True)
     a = fused_round_tail(*args, **kw, tile=64, interpret=True)

@@ -56,7 +56,7 @@ class TestPackedTraces:
         xs = rng.binomial(1, 0.3, (7, 61)).astype(np.float32)
         np.testing.assert_array_equal(np.asarray(pack_bits_jnp(jnp.asarray(xs))), pack_trace(xs))
 
-    @pytest.mark.parametrize("K,tile_b", [(7, 1024), (64, 4), (1000, 16), (8192, 1024)])
+    @pytest.mark.parametrize("K,tile_b", [(7, 1024), (64, 4), (1000, 16), (8192, 1024), (2 * 8192 + 13, 1024)])
     def test_unpack_kernel_interpret_matches_ref(self, K, tile_b):
         rng = np.random.default_rng(K)
         packed = jnp.asarray(rng.integers(0, 256, packed_width(K)), jnp.uint8)
@@ -326,7 +326,7 @@ class TestLagTraces:
         from repro.kernels.unpack_bits import unpack_crumbs_kernel_call, unpack_crumbs_ref
 
         rng = np.random.default_rng(1)
-        for K in (4, 37, 4096):
+        for K in (4, 37, 4096, 2 * 4096 + 37):  # the last spans three ragged byte tiles
             packed = jnp.asarray(rng.integers(0, 256, (K + 3) // 4, dtype=np.uint8))
             a = unpack_crumbs_ref(packed, K)
             b = unpack_crumbs_kernel_call(packed, K, tile_b=16, interpret=True)

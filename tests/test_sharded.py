@@ -304,7 +304,7 @@ class TestAllocatorDtype:
         K, k = 5000, 500
         sigma = 0.25 * k / K
         if dtype == "float64":
-            with jax.experimental.enable_x64():
+            with jax.enable_x64():
                 w = jnp.asarray(rng.gamma(0.3, 1.0, K))
                 assert w.dtype == jnp.float64
                 p, capped = masked_prob_alloc(w, k, sigma)
@@ -327,7 +327,7 @@ class TestAllocatorDtype:
 
     def test_float64_block_mode(self):
         rng = np.random.default_rng(12)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64():
             w = jnp.asarray(rng.gamma(0.3, 1.0, 2000))
             p1, _ = masked_prob_alloc(w, 200, 0.01)
             p4, _ = masked_prob_alloc(w, 200, 0.01, block=4)
