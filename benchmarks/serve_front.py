@@ -45,7 +45,7 @@ try:
 except ImportError:  # running as a script
     from common import emit, reporter
 
-from repro.obs import LatencyHistogram
+from repro.obs import LatencyHistogram, SpanTimer
 from repro.serve import SelectionServer, ServeClient, ServeError, SlotEngine
 
 
@@ -65,7 +65,7 @@ def _drive_closed(address, spec: dict, rounds: int, hist: LatencyHistogram, lock
 def bench_closed_loop(J: int, K: int, rounds: int, rep) -> float:
     # J timed tenants + 1 warm tenant share one slot bucket: the timed phase
     # reuses the exact compiled step the warmup built
-    srv = SelectionServer(SlotEngine(K_max=K, k_cap=max(8, K // 8), buckets=(J + 1,)))
+    srv = SelectionServer(SlotEngine(K_max=K, k_cap=max(8, K // 8), buckets=(J + 1,)), spans=SpanTimer())
     hist = LatencyHistogram(lo=1e-5, hi=10.0)
     lock = threading.Lock()
     with srv:

@@ -120,7 +120,7 @@ def run_service(
         batch = [queue.popleft() for _ in range(min(J, len(queue)))]
         keys = jax.vmap(lambda kk: jax.random.fold_in(kk, t))(base_keys)
         xs = jnp.asarray(np.stack([b[2] for b in batch]))
-        with spans.span("dispatch", annotate=True):
+        with spans.span("dispatch"):
             state, out = batched_step(cfg, state, keys, xs)
             jax.block_until_ready(out["idx"])
         t_done = time.perf_counter()

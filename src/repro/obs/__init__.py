@@ -7,7 +7,7 @@ Three layers, one schema:
 * :mod:`repro.obs.runlog` — schema-versioned JSONL run logs every runner,
   grid and serving loop writes through.
 * :mod:`repro.obs.trace` — stage-level trace annotations for device code
-  and bucketed host-side latency histograms.
+  and the host span recorder (``SpanTimer``, the process-wide ``SPANS``).
 
 * :mod:`repro.obs.sketches` — fixed-size mergeable client-axis sketches
   (count/probability/lag histograms, per-region rollups) carried in the
@@ -28,7 +28,7 @@ from .report import Reporter
 from .runlog import SCHEMA_VERSION, RunLog, iter_alerts, iter_metrics, read_runlog, validate_records
 from .sketches import SKETCH_FIELDS, SketchSpec, fairness_series, merge_sketches, sketch_from_dense
 from .taps import ROUND_TAPS, TapRegistry, TapSpec, window_reduce
-from .trace import LatencyHistogram, SpanTimer, stage
+from .trace import SPANS, LatencyHistogram, SpanTimer, stage
 
 __all__ = [
     "artifact_path", "bench_dir", "bench_path", "results_root", "runlog_dir", "runlog_path",
@@ -37,5 +37,5 @@ __all__ = [
     "SKETCH_FIELDS", "SketchSpec", "fairness_series", "merge_sketches", "sketch_from_dense",
     "Alert", "AlertRules", "detect_alerts", "log_alerts",
     "ROUND_TAPS", "TapRegistry", "TapSpec", "window_reduce",
-    "LatencyHistogram", "SpanTimer", "stage",
+    "LatencyHistogram", "SpanTimer", "SPANS", "stage",
 ]

@@ -41,6 +41,16 @@ history).  That is the property that makes three things fall out:
 Feedback is the population availability vector for the round being issued
 (the paper's volatility bits), as completion-lag codes: 0 = on time,
 ``1..S`` = late, ``DEAD_LAG`` = never.  See ``docs/serving.md``.
+
+Both backends record the same host spans of a tick into ``spans`` (a
+``repro.obs.trace.SpanTimer``: the process-wide ``SPANS``, or the recorder
+of the ``SelectionServer`` that serves the engine): ``engine.copy_in`` (host row prep and
+the host-to-device copy), ``engine.launch`` (the asynchronous dispatch of
+the compiled step), ``engine.wait`` (the host blocked on the device: the
+NaN/inf guard), ``engine.copy_out`` (device-to-host reads of the results)
+and ``engine.cohort`` (host post-processing), and three counters:
+``engine.ticks`` (jobs stepped), ``engine.syncs`` (blocking device-to-host
+reads) and ``engine.copy_bytes`` (bytes copied between host and device).
 """
 from __future__ import annotations
 
@@ -61,6 +71,7 @@ from repro.engine.multi_job import (
     slot_retire,
 )
 from repro.engine.round_program import staleness_ring_step
+from repro.obs.trace import SPANS
 
 from . import protocol
 
@@ -173,6 +184,7 @@ class SlotEngine:
         self.jobs: Dict[int, dict] = {}  # uid -> {"slot": int, "spec": JobSpec}
         self._next_uid = 0
         self.faults = None  # chaos hook (repro.serve.faults.FaultPlan) or None
+        self.spans = SPANS  # a SelectionServer hands its own recorder
 
     # -- capacity ---------------------------------------------------------
 
@@ -231,7 +243,10 @@ class SlotEngine:
     def job_round(self, uid: int) -> int:
         """The round the job's NEXT tick will serve (the idempotency cursor
         the transport's retry cache compares request rounds against)."""
-        return int(np.asarray(self.state.t)[self.jobs[uid]["slot"]])
+        t = np.asarray(self.state.t)
+        self.spans.add("engine.syncs")
+        self.spans.add("engine.copy_bytes", t.nbytes)
+        return int(t[self.jobs[uid]["slot"]])
 
     # -- the batched serving step ----------------------------------------
 
@@ -278,47 +293,60 @@ class SlotEngine:
         ``{"round", "cohort", "on_time", "stale"}``."""
         if self.faults is not None:
             self.faults.on_engine_step()
+        spans = self.spans
         J = self.n_slots
         if len({u for u, _ in items}) != len(items):
             raise ValueError("duplicate job uid in one batch (coalesce across dispatches)")
-        participate = np.zeros((J,), bool)
-        lag = np.zeros((J, self.K_max), np.int32)
-        rounds_before = np.asarray(self.state.t)
-        for uid, row in items:
-            job = self.jobs[uid]
-            slot, K = job["slot"], job["spec"].K
-            row = np.asarray(row, np.int32).reshape(-1)
-            if row.shape[0] != K:
-                raise ValueError(f"job {uid}: feedback has {row.shape[0]} entries, K={K}")
-            participate[slot] = True
-            lag[slot, :K] = row
-        step = self._steps.get(J)
-        if step is None:
-            step = self._steps[J] = self._build_step(J)
-        logw, t, pending, idx, on_time, stale, finite = step(
-            self.cfg, self.state.logw, self.state.t, self.pending,
-            self.base_keys, jnp.asarray(lag), jnp.asarray(participate),
-        )
+        with spans.span("engine.copy_in"):
+            participate = np.zeros((J,), bool)
+            lag = np.zeros((J, self.K_max), np.int32)
+            rounds_before = np.asarray(self.state.t)
+            for uid, row in items:
+                job = self.jobs[uid]
+                slot, K = job["slot"], job["spec"].K
+                row = np.asarray(row, np.int32).reshape(-1)
+                if row.shape[0] != K:
+                    raise ValueError(f"job {uid}: feedback has {row.shape[0]} entries, K={K}")
+                participate[slot] = True
+                lag[slot, :K] = row
+            lag_d, participate_d = jnp.asarray(lag), jnp.asarray(participate)
+        spans.add("engine.ticks", len(items))
+        spans.add("engine.syncs")  # rounds_before
+        spans.add("engine.copy_bytes", rounds_before.nbytes + lag.nbytes + participate.nbytes)
+        with spans.span("engine.launch"):
+            step = self._steps.get(J)
+            if step is None:
+                step = self._steps[J] = self._build_step(J)
+            logw, t, pending, idx, on_time, stale, finite = step(
+                self.cfg, self.state.logw, self.state.t, self.pending,
+                self.base_keys, lag_d, participate_d,
+            )
         # reassign before any raise: the step donated the old buffers, and
         # on a refused (non-finite) update the state outputs ARE the old state
         self.state = MultiJobState(logw=logw, t=t)
         self.pending = pending
-        # one host transfer for everything the response needs + the guard flag
-        idx, on_time, stale, finite = jax.device_get((idx, on_time, stale, finite))
+        with spans.span("engine.wait"):
+            jax.block_until_ready(finite)
+        with spans.span("engine.copy_out"):
+            # one host transfer for everything the response needs + the guard flag
+            idx, on_time, stale, finite = jax.device_get((idx, on_time, stale, finite))
+        spans.add("engine.syncs")
+        spans.add("engine.copy_bytes", idx.nbytes + on_time.nbytes + stale.nbytes + finite.nbytes)
         if not bool(finite):
             raise NumericsError(
                 "selector update produced non-finite log-weights; update refused"
             )
-        results = {}
-        for uid, _ in items:
-            slot = self.jobs[uid]["slot"]
-            cohort = idx[slot][idx[slot] >= 0]
-            results[uid] = {
-                "round": int(rounds_before[slot]),
-                "cohort": cohort.tolist(),
-                "on_time": float(on_time[slot]),
-                "stale": float(stale[slot]),
-            }
+        with spans.span("engine.cohort"):
+            results = {}
+            for uid, _ in items:
+                slot = self.jobs[uid]["slot"]
+                cohort = idx[slot][idx[slot] >= 0]
+                results[uid] = {
+                    "round": int(rounds_before[slot]),
+                    "cohort": cohort.tolist(),
+                    "on_time": float(on_time[slot]),
+                    "stale": float(stale[slot]),
+                }
         return results
 
     # -- checkpoint surface ----------------------------------------------
@@ -409,6 +437,7 @@ class ShardedEngine:
         self.jobs: Dict[int, dict] = {}
         self._next_uid = 0
         self.faults = None  # chaos hook (repro.serve.faults.FaultPlan) or None
+        self.spans = SPANS  # a SelectionServer hands its own recorder
 
     def _runner(self, spec: JobSpec):
         from repro.configs.base import FLConfig
@@ -458,28 +487,36 @@ class ShardedEngine:
         already device-parallel; there is no J axis to batch here)."""
         if self.faults is not None:
             self.faults.on_engine_step()
+        spans = self.spans
         results = {}
         for uid, row in items:
             job = self.jobs[uid]
             spec: JobSpec = job["spec"]
             run, _, _ = self._runner(spec)
-            row = np.asarray(row, np.int32).reshape(-1)
-            if row.shape[0] != spec.K:
-                raise ValueError(f"job {uid}: feedback has {row.shape[0]} entries, K={spec.K}")
-            if self.staleness:
-                xs = jnp.asarray(row, jnp.int32)[None, :]
-                state, key, rings, masks, lags, ps, sigmas, arrived = run(
-                    job["state"], job["key"], job["rings"], xs
-                )
-                stale = float(np.asarray(arrived[0][: spec.K]).sum())
-            else:
-                xs = jnp.asarray(row == 0, jnp.float32)[None, :]
-                state, key, masks, xbits, ps, sigmas = run(job["state"], job["key"], xs)
-                rings = None
-                stale = 0.0
+            with spans.span("engine.copy_in"):
+                row = np.asarray(row, np.int32).reshape(-1)
+                if row.shape[0] != spec.K:
+                    raise ValueError(f"job {uid}: feedback has {row.shape[0]} entries, K={spec.K}")
+                if self.staleness:
+                    xs = jnp.asarray(row, jnp.int32)[None, :]
+                else:
+                    xs = jnp.asarray(row == 0, jnp.float32)[None, :]
+            with spans.span("engine.launch"):
+                if self.staleness:
+                    state, key, rings, masks, lags, ps, sigmas, arrived = run(
+                        job["state"], job["key"], job["rings"], xs
+                    )
+                else:
+                    state, key, masks, xbits, ps, sigmas = run(job["state"], job["key"], xs)
+                    rings = None
+            spans.add("engine.ticks")
+            spans.add("engine.syncs")  # the guard
+            spans.add("engine.copy_bytes", xs.nbytes + 1)
             # NaN/inf guard: the runner does not donate, so the old state is
             # intact — refuse the update before assigning anything
-            if not bool(jnp.all(jnp.isfinite(state.e3cs.logw))):
+            with spans.span("engine.wait"):
+                finite = bool(jnp.all(jnp.isfinite(state.e3cs.logw)))
+            if not finite:
                 raise NumericsError(
                     f"job {uid}: selector update produced non-finite log-weights; "
                     "update refused"
@@ -487,15 +524,22 @@ class ShardedEngine:
             if rings is not None:
                 job["rings"] = rings
             job["state"], job["key"] = state, key
-            mask = np.asarray(masks[0][: spec.K])
-            cohort = np.nonzero(mask > 0)[0]
-            on_time = float((mask * (row == 0)).sum())
-            results[uid] = {
-                "round": job["t"],
-                "cohort": cohort.tolist(),
-                "on_time": on_time,
-                "stale": stale,
-            }
+            with spans.span("engine.copy_out"):
+                mask = np.asarray(masks[0][: spec.K])
+                late = np.asarray(arrived[0][: spec.K]) if self.staleness else None
+            reads = (mask,) if late is None else (mask, late)
+            spans.add("engine.syncs", len(reads))
+            spans.add("engine.copy_bytes", sum(a.nbytes for a in reads))
+            with spans.span("engine.cohort"):
+                stale = float(late.sum()) if late is not None else 0.0
+                cohort = np.nonzero(mask > 0)[0]
+                on_time = float((mask * (row == 0)).sum())
+                results[uid] = {
+                    "round": job["t"],
+                    "cohort": cohort.tolist(),
+                    "on_time": on_time,
+                    "stale": stale,
+                }
             job["t"] += 1
         return results
 

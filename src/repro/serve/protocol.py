@@ -41,6 +41,8 @@ __all__ = [
     "ConnectionClosed",
     "send_message",
     "recv_message",
+    "recv_header",
+    "recv_body",
     "encode_bits",
     "decode_bits",
     "encode_lags",
@@ -88,9 +90,21 @@ def send_message(sock: socket.socket, obj: dict) -> None:
 
 def recv_message(sock: socket.socket, max_bytes: int = MAX_MESSAGE_BYTES) -> dict:
     """Read one frame; raises ``ConnectionClosed`` on clean EOF."""
+    return recv_body(sock, recv_header(sock, max_bytes))
+
+
+def recv_header(sock: socket.socket, max_bytes: int = MAX_MESSAGE_BYTES) -> int:
+    """Wait for the next frame's length prefix; raises ``ConnectionClosed``
+    on clean EOF.  The server times what follows (``recv_body``) apart from
+    this wait for the peer."""
     (length,) = _HEADER.unpack(_recv_exact(sock, _HEADER.size, at_boundary=True))
     if length > max_bytes:
         raise ProtocolError(f"peer announced a {length}-byte frame (max {max_bytes})")
+    return length
+
+
+def recv_body(sock: socket.socket, length: int) -> dict:
+    """Read and parse the ``length``-byte JSON body after a frame's header."""
     body = _recv_exact(sock, length, at_boundary=False)
     try:
         obj = json.loads(body)

@@ -54,13 +54,25 @@ engine (``repro.serve.engines``).  The moving parts:
   hook a no-op.
 
 Per-dispatch telemetry (queue depth, batch width, sheds, restarts and
-recovery latency — the ``serve`` group of ``ROUND_TAPS``) and a
-dispatch-latency ``LatencyHistogram`` accumulate on the server;
-``attach_report`` hands them to a ``Reporter`` so server runs land in bench
-JSON / run logs like any engine run.
+recovery latency — the ``serve`` group of ``ROUND_TAPS``) accumulates on the
+server; ``attach_report`` hands it, with the ``serve.tick`` span's latency
+histogram, to a ``Reporter`` so server runs land in bench JSON / run logs
+like any engine run.
+
+Spans (``repro.obs.trace.SpanTimer``; the server's ``spans``, by default the
+process-wide ``SPANS``, which the engine records into too): every request
+gets a request id when its frame header arrives, and the spans of its path
+carry it — ``serve.parse`` (body read + JSON parse, handler thread),
+``serve.queue`` (enqueue until the engine thread's dispatch takes it),
+``serve.decode`` (feedback decode), ``serve.tick`` (the engine call; parent
+of the engine's ``engine.*`` spans) and ``serve.reply`` (from the engine
+thread's answer to the reply's last byte on the socket).  The ``stats`` op
+returns each span's count, p50, p95 and max, and the engine's counters, so
+an operator reads them off a live server.
 """
 from __future__ import annotations
 
+import itertools
 import logging
 import queue
 import socket
@@ -70,7 +82,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.obs import ROUND_TAPS, LatencyHistogram
+from repro.obs import ROUND_TAPS
+from repro.obs.trace import SPANS, SpanTimer
 from repro.obs.alerts import Alert, log_alerts
 
 from . import protocol
@@ -85,18 +98,24 @@ log = logging.getLogger("repro.serve")
 
 
 class _Item:
-    """One queued request: parsed op + the handler's rendezvous."""
+    """One queued request: parsed op + the handler's rendezvous, its
+    request id and the ``perf_counter_ns`` times it was queued and answered
+    (the ``serve.queue`` and ``serve.reply`` spans)."""
 
-    __slots__ = ("req", "deadline", "event", "response")
+    __slots__ = ("req", "deadline", "event", "response", "rid", "t_enq", "t_resp")
 
-    def __init__(self, req: dict, deadline: float):
+    def __init__(self, req: dict, deadline: float, rid: int = -1):
         self.req = req
         self.deadline = deadline
+        self.rid = rid
         self.event = threading.Event()
         self.response: Optional[dict] = None
+        self.t_enq = time.perf_counter_ns()
+        self.t_resp = 0
 
     def respond(self, resp: dict) -> None:
         self.response = resp
+        self.t_resp = time.perf_counter_ns()
         self.event.set()
 
 
@@ -109,7 +128,8 @@ class SelectionServer:
 
     ``port=0`` binds an ephemeral port — read it back from ``address`` after
     ``start()``.  The server is also a context manager (``with`` = start /
-    graceful close).
+    graceful close).  ``spans`` is the span recorder the server and its
+    engine record into (default: the process-wide ``SPANS``).
     """
 
     def __init__(
@@ -128,8 +148,12 @@ class SelectionServer:
         max_restarts: int = 8,
         restart_backoff: float = 0.05,
         stop_timeout: float = 60.0,
+        spans: Optional[SpanTimer] = None,
     ):
         self.engine = engine
+        self.spans = spans if spans is not None else SPANS
+        engine.spans = self.spans
+        self._rids = itertools.count()  # request ids, assigned as frames arrive
         self._host, self._port = host, int(port)
         self.max_queue = int(max_queue)
         self.max_batch = int(max_batch)  # 0 = no cap beyond queue coalescing
@@ -163,7 +187,6 @@ class SelectionServer:
         self._rounds_since_ckpt = 0
         self.rounds_served = 0
         self.serve_rows: List[Dict[str, float]] = []
-        self.latency = LatencyHistogram(lo=1e-5, hi=60.0)
         self.recoveries: List[float] = []  # crash-to-restored latencies (s)
         self.alerts: List[Alert] = []  # engine_restart / numerics events
         self._tick_cache: Dict[int, Tuple[int, dict]] = {}  # uid -> (round, response)
@@ -275,16 +298,21 @@ class SelectionServer:
         try:
             while not self._stopped.is_set():
                 try:
-                    req = protocol.recv_message(conn)
+                    length = protocol.recv_header(conn)
+                    rid = next(self._rids)
+                    with self.spans.span("serve.parse", rid=rid):
+                        req = protocol.recv_body(conn, length)
                 except protocol.ConnectionClosed:
                     break
                 except protocol.ProtocolError as e:
                     protocol.send_message(conn, _err("bad_request", str(e)))
                     break
-                resp = self._submit(req)
+                resp, t_resp = self._submit(req, rid)
                 if self.faults is not None and self.faults.on_response():
                     break  # fault-injected connection drop: response lost
                 protocol.send_message(conn, resp)
+                if t_resp:
+                    self.spans.record("serve.reply", t_resp, time.perf_counter_ns(), rid=rid)
                 if req.get("op") == "shutdown":
                     break
         except OSError:
@@ -294,26 +322,28 @@ class SelectionServer:
                 self._conns.discard(conn)
             conn.close()
 
-    def _submit(self, req: dict) -> dict:
+    def _submit(self, req: dict, rid: int) -> Tuple[dict, int]:
         """Admission control: queue the request for the engine thread and
-        wait for its response (shed instead of queueing when full)."""
+        wait for its response (shed instead of queueing when full).  Returns
+        the response and the ``perf_counter_ns`` time the engine thread
+        answered it (0 when the engine never saw it)."""
         if self._engine_dead.is_set():
-            return _err("engine_down", "engine restart budget exhausted; server needs operator attention")
+            return _err("engine_down", "engine restart budget exhausted; server needs operator attention"), 0
         if self._draining.is_set():
-            return _err("draining", "server is draining; no new requests")
-        item = _Item(req, time.monotonic() + self.request_timeout)
+            return _err("draining", "server is draining; no new requests"), 0
+        item = _Item(req, time.monotonic() + self.request_timeout, rid)
         try:
             self._queue.put_nowait(item)
         except queue.Full:
             with self._lock:
                 self.stats["shed"] += 1
                 self._shed_window += 1
-            return _err("shed", f"admission queue at capacity ({self.max_queue})")
+            return _err("shed", f"admission queue at capacity ({self.max_queue})"), 0
         # The engine thread guarantees a response for every queued item; the
         # extra margin only matters if it died mid-request.
         if not item.event.wait(self.request_timeout * 2 + 60.0):
-            return _err("internal", "engine thread unresponsive")
-        return item.response
+            return _err("internal", "engine thread unresponsive"), 0
+        return item.response, item.t_resp
 
     # -- engine side -------------------------------------------------------
 
@@ -370,6 +400,7 @@ class SelectionServer:
             engine, step = load_server(stem)
             if self.faults is not None:
                 engine.faults = self.faults
+            engine.spans = self.spans
             self.engine = engine
             self.rounds_served = restored_step = step
             self._rounds_since_ckpt = 0
@@ -490,9 +521,12 @@ class SelectionServer:
         if not batch:
             return
         now = time.monotonic()
+        t_take = time.perf_counter_ns()
+        spans = self.spans
         live: List[_Item] = []
         items: List[Tuple[int, np.ndarray]] = []
         for item in batch:
+            spans.record("serve.queue", item.t_enq, t_take, rid=item.rid)
             if now > item.deadline:
                 with self._lock:
                     self.stats["timeouts"] += 1
@@ -527,7 +561,8 @@ class SelectionServer:
                     continue
             spec: JobSpec = job["spec"]
             try:
-                lag = protocol.feedback_lags(item.req, spec.K, self.engine.staleness)
+                with spans.span("serve.decode", rid=item.rid):
+                    lag = protocol.feedback_lags(item.req, spec.K, self.engine.staleness)
             except protocol.ProtocolError as e:
                 item.respond(_err("bad_request", str(e)))
                 continue
@@ -538,9 +573,10 @@ class SelectionServer:
             items.append((uid, lag))
         if not items:
             return
-        t0 = time.perf_counter()
         try:
-            results = self.engine.tick(items)
+            # a batch of several requests carries its first request's id
+            with spans.span("serve.tick", rid=live[0].rid):
+                results = self.engine.tick(items)
         except (ValueError, TypeError, KeyError) as e:  # rejected batch: fail its requests
             with self._lock:
                 self.stats["errors"] += len(live)
@@ -564,7 +600,6 @@ class SelectionServer:
             for item in live:
                 item.respond(_err("retry", f"engine crashed mid-dispatch ({e}); retry"))
             raise
-        self.latency.observe(time.perf_counter() - t0)
         with self._lock:
             self.stats["dispatches"] += 1
             self.stats["ticks"] += len(items)
@@ -628,7 +663,11 @@ class SelectionServer:
             if op == "stats":
                 with self._lock:
                     stats = dict(self.stats)
-                return {"ok": True, "stats": stats, "rounds_served": self.rounds_served}
+                return {
+                    "ok": True, "stats": stats, "rounds_served": self.rounds_served,
+                    "spans": self.spans.digest(), "counters": dict(self.spans.counters),
+                    "spans_dropped": self.spans.dropped,
+                }
             if op == "checkpoint":
                 if not self.ckpt_dir:
                     return _err("bad_request", "server has no ckpt_dir")
@@ -670,13 +709,13 @@ class SelectionServer:
     def attach_report(self, reporter, window: int = SERVE_WINDOW) -> None:
         """Emit this server's run into a ``Reporter``: the windowed ``serve``
         metric stream (gated by the tap group's directions) + the dispatch
-        latency histogram + scalar stats."""
+        latency histogram (the ``serve.tick`` span's) + scalar stats."""
         if len(self.serve_rows) >= window:
             reporter.metrics_stream(
                 "serve", self.serve_series(), window=window,
                 better=ROUND_TAPS.directions("serve"),
             )
-        reporter.histogram("dispatch", self.latency)
+        reporter.histogram("dispatch", self.spans.get("serve.tick"))
         reporter.update(rounds_served=self.rounds_served, **{f"n_{k}": v for k, v in self.stats.items()})
         if self.alerts:  # supervisor events (engine_restart / numerics)
             if reporter.log is not None:

@@ -17,6 +17,7 @@ import importlib.util
 import json
 import math
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -846,10 +847,140 @@ class TestLatencyHistogram:
         t = SpanTimer()
         with t.span("work"):
             pass
-        with t.span("work", annotate=True):
+        with t.span("work"):
             pass
         assert t.get("work").summary()["count"] == 2
         assert "work" in t.summary()
+        assert t.durations_ns("work").size == 2 and t.quantile("work", 0.5) >= 0
+
+    def test_lifetime_histogram_outlives_the_ring(self):
+        t = SpanTimer(capacity=4)
+        for d in (0, 1_000, 50_000_000_000, 2_000, 3_000, 4_000):  # ns; 50 s clamps to the top bucket
+            t.record("work", 10, 10 + d)
+        h = t.get("work")
+        assert t.dropped == 2 and t.durations_ns("work").size == 4
+        assert h.count == 6 and int(h.counts.sum()) == 6
+        assert (h.min, h.max) == (0.0, 50.0) and h.counts[-1] == 1
+        assert h.sum == pytest.approx(50 + 1e-5, rel=1e-12)
+        t.get("work").observe(float("nan"))  # dropped, as before
+        assert h.count == 6
+
+
+class TestSpanRecorder:
+    """``SpanTimer`` as the host span recorder: ring, ids, quantiles, the
+    profiler's timeline."""
+
+    def test_parent_ids_when_spans_nest(self):
+        t = SpanTimer()
+        with t.span("outer", rid=5) as outer:
+            with t.span("mid") as mid:
+                with t.span("inner") as inner:
+                    pass
+            with t.span("sibling") as sib:
+                pass
+        with t.span("root") as root:
+            pass
+        ev = t.events()
+        by = {n: i for i, n in enumerate(ev["name"])}
+        assert ev["parent"][by["outer"]] == -1 and ev["parent"][by["root"]] == -1
+        assert ev["parent"][by["mid"]] == outer.id and ev["parent"][by["sibling"]] == outer.id
+        assert ev["parent"][by["inner"]] == mid.id
+        assert [ev["id"][by[n]] for n in ("inner", "sibling", "root")] == [inner.id, sib.id, root.id]
+        # the request id is inherited from the enclosing span, else -1
+        assert [ev["rid"][by[n]] for n in ("outer", "mid", "inner", "sibling", "root")] == [5, 5, 5, 5, -1]
+        assert len(set(ev["id"].tolist())) == 5
+
+    def test_request_id_kept_across_two_threads(self):
+        import threading
+
+        t = SpanTimer()
+        handed = {}
+
+        def handler():
+            with t.span("front", rid=42) as sp:
+                handed["t0"] = time.perf_counter_ns()
+                handed["front"] = sp.id
+
+        def engine():
+            t.record("wait", handed["t0"], time.perf_counter_ns(), rid=42)
+            with t.span("work", rid=42):
+                with t.span("step"):
+                    pass
+
+        for fn in (handler, engine):
+            th = threading.Thread(target=fn)
+            th.start()
+            th.join()
+        ev = t.events()
+        assert sorted(ev["name"][ev["rid"] == 42]) == ["front", "step", "wait", "work"]
+        # parents never cross threads: each thread's stack is its own
+        assert ev["parent"][ev["name"] == "wait"][0] == -1
+        assert ev["parent"][ev["name"] == "front"][0] == -1
+
+    def test_ring_wraps_with_an_exact_dropped_count(self):
+        t = SpanTimer(capacity=8)
+        for i in range(21):
+            t.record("a" if i % 3 else "b", 1000 * i, 1000 * i + i, rid=i)
+        assert t.dropped == 13
+        ev = t.events()
+        assert ev["rid"].tolist() == list(range(13, 21))  # the newest 8, oldest first
+        assert (ev["t1_ns"] - ev["t0_ns"]).tolist() == list(range(13, 21))
+        assert ev["name"].tolist() == ["a" if i % 3 else "b" for i in range(13, 21)]
+        # the lifetime histograms still hold every span
+        assert t.get("a").count + t.get("b").count == 21 and t.get("b").count == 7
+        t.reset()
+        assert t.dropped == 0 and t.events()["name"].size == 0 and t.counters == {}
+
+    def test_ring_quantiles_equal_numpy_quantile(self):
+        t = SpanTimer()
+        rng = np.random.default_rng(1)
+        dur = rng.integers(1_000, 10_000_000, 999)
+        for i, d in enumerate(dur):
+            t.record("x", 10 * i, 10 * i + int(d))
+            t.record("y", 0, 1)
+        for q in (0.0, 0.5, 0.95, 0.99, 1.0):
+            assert t.quantile("x", q) == float(np.quantile(dur, q)) * 1e-9
+        d = t.digest()["x"]
+        assert d["count"] == 999 and d["max_ms"] == dur.max() * 1e-6
+        assert d["p50_ms"] == pytest.approx(np.quantile(dur, 0.5) * 1e-6, rel=1e-12)
+        assert d["p95_ms"] == pytest.approx(np.quantile(dur, 0.95) * 1e-6, rel=1e-12)
+        assert t.quantile("never", 0.5) is None
+
+    def test_record_with_an_external_start(self):
+        t = SpanTimer()
+        t0 = time.perf_counter_ns()
+        time.sleep(0.002)
+        with t.span("dispatch", rid=9) as sp:
+            sid = t.record("queue", t0, time.perf_counter_ns())
+        ev = t.events()
+        (i,) = np.nonzero(ev["name"] == "queue")[0]
+        assert ev["id"][i] == sid and ev["parent"][i] == sp.id and ev["rid"][i] == 9
+        assert ev["t0_ns"][i] == t0 and ev["t1_ns"][i] - t0 >= 2_000_000
+        t.add("bytes", 4096)
+        t.add("bytes", 4)
+        t.add("syncs")
+        assert t.counters == {"bytes": 4100, "syncs": 1}
+
+    def test_span_lands_on_the_profilers_host_timeline(self, tmp_path):
+        """A span's name is found in a ``jax.profiler`` trace read with
+        ``ProfileData.from_file``, and its ring start, placed on the wall
+        clock through the anchor, lies where the profiler put it."""
+        t = SpanTimer()
+        jax.profiler.start_trace(str(tmp_path))
+        for i in range(9):
+            with t.span("unit.traced_span", rid=i):
+                time.sleep(0.001)
+        jax.profiler.stop_trace()
+        (path,) = sorted(tmp_path.rglob("*.xplane.pb"))
+        pd = jax.profiler.ProfileData.from_file(str(path))
+        starts, t_start = [], None
+        for plane in pd.planes:
+            t_start = dict(plane.stats).get("profile_start_time", t_start)
+            for line in plane.lines:
+                starts += [e.start_ns for e in line.events if e.name == "unit.traced_span"]
+        assert len(starts) == 9 and t_start is not None
+        ring = t.wall_ns(t.events()["t0_ns"]) - t_start
+        assert float(np.median(np.abs(np.sort(starts) - ring))) < 200e3  # ns
 
 
 class TestStage:

@@ -229,6 +229,49 @@ def test_transport_roundtrip_and_errors():
             assert e.value.code == "unknown_job"
 
 
+TICK_SPANS = {"serve.parse", "serve.queue", "serve.decode", "serve.tick", "serve.reply",
+              "engine.copy_in", "engine.launch", "engine.wait", "engine.copy_out", "engine.cohort"}
+
+
+@pytest.mark.parametrize("kind", ["sharded", "slots"])
+def test_served_tick_records_every_span_once(kind):
+    """One served sync tick records each span of its path exactly once,
+    all under the request id its frame got; the engine spans hang off the
+    dispatch's ``serve.tick``; the counters count what crossed; the
+    ``stats`` op reports the spans."""
+    from repro.obs.trace import SpanTimer
+
+    K = 4096
+    engine = ShardedEngine(D=1) if kind == "sharded" else SlotEngine(K_max=K, k_cap=16, buckets=(4,))
+    spans = SpanTimer()
+    with SelectionServer(engine, spans=spans) as srv:
+        assert engine.spans is spans
+        with ServeClient.connect(srv.address) as c:
+            job = c.admit(K=K, k=16, seed=3, rounds=10)
+            before = dict(spans.counters)
+            out = c.tick(job, bits=np.random.default_rng(0).random(K) < 0.7)
+            assert out["round"] == 0 and len(out["cohort"]) == 16
+            stats = c.stats()  # the tick's reply span is recorded before this request is read
+    ev = spans.events()
+    (rid,) = ev["rid"][ev["name"] == "serve.decode"]
+    mine = ev["rid"] == rid
+    names = sorted(ev["name"][mine])
+    assert names == sorted(TICK_SPANS)
+    (tick_id,) = ev["id"][mine & (ev["name"] == "serve.tick")]
+    engine_spans = mine & np.array([n.startswith("engine.") for n in ev["name"]])
+    assert np.all(ev["parent"][engine_spans] == tick_id)
+    assert np.all(ev["t1_ns"][mine] >= ev["t0_ns"][mine])
+    assert set(stats["spans"]) >= TICK_SPANS and stats["spans"]["serve.tick"]["count"] == 1
+    assert {"count", "p50_ms", "p95_ms", "max_ms"} == set(stats["spans"]["serve.tick"])
+    assert stats["counters"]["engine.ticks"] == 1 and stats["spans_dropped"] == 0
+    delta = {k: v - before.get(k, 0) for k, v in spans.counters.items()}
+    if kind == "sharded":
+        # float32 row in, float32 mask out, the guard's one byte
+        assert delta == {"engine.ticks": 1, "engine.syncs": 2, "engine.copy_bytes": 8 * K + 1}
+    else:
+        assert delta["engine.ticks"] == 1 and delta["engine.syncs"] >= 2
+
+
 def test_transport_concurrent_clients_batch():
     """Two clients hammering concurrently: every response is consistent and
     per-job rounds stay strictly sequential no matter how dispatches
