@@ -94,7 +94,8 @@ from repro.engine.sharded import (
     masked_prob_alloc,
     masked_prob_alloc_scalars,
 )
-from repro.fl.round import ServerState, init_server_state, make_select_fn
+from repro.fl.round import ServerState, init_server_state, make_alloc_fn, make_select_fn
+from repro.kernels.radix_select import threshold_select_route, topk_mask
 from repro.kernels.round_fused import fused_alloc_select, fused_perturb_select, fused_round_tail
 from repro.kernels.unpack_bits import unpack_bits, unpack_crumbs
 from repro.obs.sketches import SKETCH_FIELDS, SketchSpec, lag_bins, region_ids, sketch_carry0, sketch_step
@@ -113,6 +114,7 @@ __all__ = [
 OBSERVE_MODES = ("none", "dense", "packed", "packed_lags")
 FEEDBACK_MODES = ("deadline", "late_credit")
 _LAG_DEAD_CODE = 3  # 2-bit crumb sentinel (see repro.scenarios.replay)
+_NO_TIES = False  # the topk_ties gauge of select paths without a threshold
 
 
 # ---------------------------------------------------------------------------
@@ -159,6 +161,13 @@ def staleness_ring_step(pending, mask, lag, S: int, alpha: float):
 # ---------------------------------------------------------------------------
 # Placement contexts: what differs between dense and K-sharded execution
 # ---------------------------------------------------------------------------
+#
+# ``ctx.select(state, key)`` returns ``(idx, p, capped, sigma, mask, ties)``.
+# The E3CS Plackett-Luce round on one placement (``_LocalCtx`` unfused, and
+# ``_ShardCtx`` at D=1) writes the mask by a threshold select
+# (``repro.kernels.radix_select``) where its kernel runs
+# (``threshold_select_route``: a TPU) and returns ``idx=None``: nothing
+# downstream reads ids there.  ``ties`` feeds the ``topk_ties`` gauge.
 
 
 class _LocalCtx:
@@ -195,14 +204,27 @@ class _LocalCtx:
                         p, capped = e3cs_probs(state.e3cs, k, sigma)
                     with stage("round.sample"):
                         _, idx = fused_perturb_select(p, g, k, tile=tile)
-                return idx, p, capped, sigma, selection_mask(idx, K)
+                return idx, p, capped, sigma, selection_mask(idx, K), _NO_TIES
+
+        elif fl.scheme == "e3cs" and fl.sampler == "plackett_luce" and threshold_select_route():
+            # the cohort mask straight from the score field: a threshold
+            # select in place of lax.top_k's sort (nothing here reads ids)
+            alloc = make_alloc_fn(fl)
+            quota_fn = program.quota_fn
+
+            def select(state, rng):
+                sigma = quota_fn(state.t)
+                p, capped = alloc(state, sigma)
+                with stage("round.sample"):
+                    mask, ties = topk_mask(perturbed_scores(rng, p), k)
+                return None, p, capped, sigma, mask, ties
 
         else:
             base = make_select_fn(fl, program.quota_fn, program.rho)
 
             def select(state, rng):
                 idx, p, capped, sigma = base(state, rng)
-                return idx, p, capped, sigma, selection_mask(idx, K)
+                return idx, p, capped, sigma, selection_mask(idx, K), _NO_TIES
 
         self.select = select
         self.observe = _make_observe(program, K_loc=K, fold=lambda key: key)
@@ -233,6 +255,7 @@ class _ShardCtx:
         self.active = active_loc
         self.e3cs_kwargs = dict(K=K, axis_name=axis_name, active=active_loc)
         quota_fn = program.quota_fn
+        threshold = D == 1 and threshold_select_route()
 
         def select(state, k1):
             sigma = quota_fn(state.t)
@@ -269,6 +292,13 @@ class _ShardCtx:
                             w, k, sigma, active=active_loc, n_iters=program.n_iters,
                             tile=program.tile, axis_name=axis_name, block=program.block,
                         )
+                    if threshold:
+                        # one shard holds every client: the threshold
+                        # select writes the mask (no candidate merge)
+                        with stage("round.sample"):
+                            scores = jnp.where(active_loc > 0, perturbed_scores(k1, p), -jnp.inf)
+                            mask, ties = topk_mask(scores, k)
+                        return None, p, capped, sigma, mask, ties
                     k_sel = jax.random.fold_in(k1, d) if D > 1 else k1
                     scores = jnp.where(active_loc > 0, perturbed_scores(k_sel, p), -jnp.inf)
                     idx = _shard_topk_merge(scores, k, axis_name)
@@ -292,7 +322,7 @@ class _ShardCtx:
                 p = jnp.full((Ks,), k / K)
             elif scheme != "e3cs":
                 p = mask
-            return idx, p, capped, sigma, mask
+            return idx, p, capped, sigma, mask, _NO_TIES
 
         self.select = select
         fold = (lambda key: jax.random.fold_in(key, d)) if D > 1 else (lambda key: key)
@@ -388,7 +418,7 @@ def _make_step(program: "RoundProgram", ctx, lean: bool, taps: bool = False,
         if region is None:
             region = jnp.asarray(region_ids(sketch, ctx.K_loc))
 
-    def tap_row(mask, x, sigma, capped, arriving=None):
+    def tap_row(mask, x, sigma, capped, ties, arriving=None):
         stale = jnp.zeros((), jnp.float32) if arriving is None else ctx.psum(jnp.sum(arriving))
         return {
             "selected": ctx.psum(jnp.sum(mask)),
@@ -396,6 +426,7 @@ def _make_step(program: "RoundProgram", ctx, lean: bool, taps: bool = False,
             "stale": stale,
             "sigma": jnp.asarray(sigma, jnp.float32),
             "capped_frac": ctx.psum(jnp.sum(capped.astype(jnp.float32))) / K_glob,
+            "topk_ties": jnp.asarray(ties, jnp.float32),  # global already (one shard)
         }
 
     def step(carry, x_over):
@@ -417,7 +448,7 @@ def _make_step(program: "RoundProgram", ctx, lean: bool, taps: bool = False,
         key, k1, k2 = jax.random.split(key, 3)
         # allocate + select
         with stage("round.select"):
-            idx, p, capped, sigma, mask = ctx.select(state, k1)
+            idx, p, capped, sigma, mask, ties = ctx.select(state, k1)
         if fused:
             # observe-decode + Eq. 16/17 elementwise + credit rings in ONE
             # fused pass (repro.kernels.round_fused); only the recenter —
@@ -485,7 +516,7 @@ def _make_step(program: "RoundProgram", ctx, lean: bool, taps: bool = False,
             )
             out = (ctx.psum(jnp.vdot(mask, x)), sigma) if lean else (mask, x, p, sigma)
             if taps:
-                row = tap_row(mask, x, sigma, capped)
+                row = tap_row(mask, x, sigma, capped, ties)
                 new_tapc = ROUND_TAPS.accumulate(tapc, row)
                 if sketch is not None:
                     skc2, sk_row = sketch_step(
@@ -534,7 +565,7 @@ def _make_step(program: "RoundProgram", ctx, lean: bool, taps: bool = False,
         )
         out = (on_time, stale, sigma) if lean else (mask, lag, p, sigma, arriving)
         if taps:
-            row = tap_row(mask, x, sigma, capped, arriving)
+            row = tap_row(mask, x, sigma, capped, ties, arriving)
             new_tapc = ROUND_TAPS.accumulate(tapc, row)
             if sketch is not None:
                 skc2, sk_row = sketch_step(

@@ -30,6 +30,7 @@ from .client import make_local_update
 __all__ = [
     "ServerState",
     "init_server_state",
+    "make_alloc_fn",
     "make_select_fn",
     "make_cohort_round",
     "make_async_cohort_round",
@@ -63,27 +64,37 @@ def init_server_state(params, K: int, vol_state) -> ServerState:
     )
 
 
-def make_select_fn(fl_cfg, quota_fn, rho=None):
-    """Returns jitted select(state, rng) -> (idx, p, capped, sigma)."""
-    K, k = fl_cfg.K, fl_cfg.k
-
+def make_alloc_fn(fl_cfg):
+    """Returns alloc(state, sigma) -> (p, capped): E3CS's ProbAlloc with the
+    configured allocator, under the ``round.allocate`` scope."""
+    k = fl_cfg.k
     allocator = getattr(fl_cfg, "allocator", "sort")
     if allocator not in ("sort", "bisect"):
         raise ValueError(f"unknown allocator {allocator!r} (want 'sort' or 'bisect')")
 
+    def alloc(state: ServerState, sigma):
+        with stage("round.allocate"):
+            if allocator == "bisect":
+                # sort-free fixed point (the shardable engine allocator);
+                # lazy import — repro.engine depends on this module
+                from repro.engine.sharded import masked_prob_alloc
+
+                w = jnp.exp(state.e3cs.logw - jnp.max(state.e3cs.logw))
+                return masked_prob_alloc(w, k, sigma)
+            return e3cs_probs(state.e3cs, k, sigma)
+
+    return alloc
+
+
+def make_select_fn(fl_cfg, quota_fn, rho=None):
+    """Returns jitted select(state, rng) -> (idx, p, capped, sigma)."""
+    K, k = fl_cfg.K, fl_cfg.k
+    alloc = make_alloc_fn(fl_cfg)
+
     def select(state: ServerState, rng: jax.Array):
         sigma = quota_fn(state.t)
         if fl_cfg.scheme == "e3cs":
-            with stage("round.allocate"):
-                if allocator == "bisect":
-                    # sort-free fixed point (the shardable engine allocator);
-                    # lazy import — repro.engine depends on this module
-                    from repro.engine.sharded import masked_prob_alloc
-
-                    w = jnp.exp(state.e3cs.logw - jnp.max(state.e3cs.logw))
-                    p, capped = masked_prob_alloc(w, k, sigma)
-                else:
-                    p, capped = e3cs_probs(state.e3cs, k, sigma)
+            p, capped = alloc(state, sigma)
             with stage("round.sample"):
                 idx = sample_selection(rng, p, k, fl_cfg.sampler)
         elif fl_cfg.scheme == "random":

@@ -140,6 +140,8 @@ ROUND_TAPS = TapRegistry(
     TapSpec("stale", "gauge", "decayed alpha**lag late credit arriving this round"),
     TapSpec("sigma", "gauge", "fairness quota floor in force this round"),
     TapSpec("capped_frac", "gauge", "fraction of the population at the ProbAlloc p<=1 cap"),
+    TapSpec("topk_ties", "gauge", "1 on rounds whose k-th score tied one left out (the threshold "
+            "select broke ties by index)"),
     TapSpec("rounds", "counter", "rounds executed"),
     TapSpec("cum_selected", "counter", "cumulative cohort slots issued", source=("selected",)),
     TapSpec("cum_credit", "counter", "running staleness-aware CEP", source=("on_time", "stale")),

@@ -679,11 +679,13 @@ class TestTapRegistry:
     def test_round_taps_schema(self):
         # the default "round" group is exactly the in-scan gauges — the
         # fairness group (host-derived from sketches) must not leak into it
-        assert set(ROUND_TAPS.gauge_names()) == {"selected", "on_time", "stale", "sigma", "capped_frac"}
+        assert set(ROUND_TAPS.gauge_names()) == {
+            "selected", "on_time", "stale", "sigma", "capped_frac", "topk_ties",
+        }
         assert ROUND_TAPS.directions()["selected"] == "equal"
         assert ROUND_TAPS.directions()["on_time"] == "higher"
         assert set(ROUND_TAPS.gauge_names(group=None)) == {
-            "selected", "on_time", "stale", "sigma", "capped_frac",
+            "selected", "on_time", "stale", "sigma", "capped_frac", "topk_ties",
             "jain", "gini", "top_decile_share", "region_cep_skew",
             "queue_depth", "batch_jobs", "shed", "restarts", "recovery_s",
         }

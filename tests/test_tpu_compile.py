@@ -6,7 +6,8 @@ between lane layouts, vector loop carries.  These tests lower and compile
 each main-path kernel at K=10,000,000 and tile 8192 for a described,
 unattached ``v5e:2x2`` chip — the TPU compiler runs here, nothing executes —
 and check that the kernel is really in the compiled program; the fused
-K-sharded round is compiled whole for the four chips of the mesh.
+K-sharded round is compiled whole for the four chips of the mesh, and the
+benchmark cells' staged rounds for one chip (no sort in either).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, so a worker that merely imports
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.kernels.bisect_tiles import bisect_block_sums_kernel_call
 from repro.kernels.gumbel_topk import gumbel_topk_kernel_call
+from repro.kernels.radix_select import count_ge_kernel_call
 from repro.kernels.round_fused import fused_select_kernel_call, round_tail_kernel_call
 from repro.kernels.unpack_bits import unpack_bits_kernel_call, unpack_crumbs_kernel_call
 
@@ -108,6 +110,16 @@ def test_bisect_block_sums(one_chip, n_caps):
     )
 
 
+@pytest.mark.parametrize("n,n_thr", [(K, 3), (K, 15), (1_000_000, 3), (1000, 3)],
+                         ids=["1e7-3probes", "1e7-15probes", "1e6-3probes", "1e3-3probes"])
+def test_count_ge(one_chip, n, n_thr):
+    # K=1e7 and 1e6 end in a partial block; 1000 keys are less than one tile
+    _compile(
+        count_ge_kernel_call,
+        _spec(one_chip, (n,), jnp.int32), _spec(one_chip, (n_thr,), jnp.int32),
+    )
+
+
 @pytest.mark.parametrize("unpack,cpb", [(unpack_bits_kernel_call, 8), (unpack_crumbs_kernel_call, 4)],
                          ids=["bits", "crumbs"])
 def test_unpack(one_chip, unpack, cpb):
@@ -144,6 +156,40 @@ def test_sharded_fused_round(topo, monkeypatch, sync):
     text = run.lower(state, _spec(replicated, (2,), jnp.uint32), xs).compile().as_text()
     assert "tpu_custom_call" in text
     assert re.search(rf"\[{4 * k}\]\S* (?:all-gather|all-reduce)", text)
+
+
+@pytest.mark.parametrize("cell", ["fleet", "coord"])
+def test_staged_round_sorts_nothing(topo, monkeypatch, cell):
+    """The benchmark cells' staged rounds on one chip: the fleet's dense
+    round (K=1e7, packed replay) and the coord's ``ShardedEngine(D=1)``
+    round (K=1e6 on a 1-device mesh, dense rows).  The select stage is the
+    threshold select's kernel, and the program holds no sort (``lax.top_k``
+    lowers to one over K)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.configs.base import FLConfig
+    from repro.engine.round_program import RoundProgram
+    from repro.obs.taps import ROUND_TAPS
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("shards",))
+    on_chip = NamedSharding(mesh, PartitionSpec())
+    K_cell = 10_000_000 if cell == "fleet" else 1_000_000
+    fl = FLConfig(K=K_cell, k=520, rounds=10**9, scheme="e3cs", allocator="bisect", quota_frac=0.5)
+    if cell == "fleet":
+        program = RoundProgram.from_config(fl, override="packed", block=4)
+        run, state0 = program.build_runner(outputs="lean", carry_key=True, taps=True, scan_length=2)
+        rest = (jax.tree.map(lambda a: _spec(on_chip, (), a.dtype), ROUND_TAPS.init_counters()),
+                _spec(on_chip, (2, K_cell // 8), jnp.uint8))
+    else:
+        program = RoundProgram.from_config(fl, mesh=mesh, override="dense", block=4)
+        run, state0 = program.build_runner(outputs="full", carry_key=True, scan_length=1)
+        rest = (_spec(on_chip, (1, K_cell)),)
+    state = jax.tree.map(lambda a: _spec(on_chip, np.shape(a), a.dtype), state0)
+    text = run.lower(state, _spec(on_chip, (2,), jnp.uint32), *rest).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert " sort(" not in text
 
 
 def test_described_chip_is_a_v5e(one_chip):
