@@ -84,10 +84,11 @@ from repro.core.selection import (
     ucb_select,
     ucb_update,
 )
-from repro.core.selection.sampling import merge_topk_candidates, perturbed_scores
+from repro.core.selection.sampling import perturbed_scores
 from repro.core.volatility import DEAD_LAG
 from repro.engine.sharded import (
     _axis_size,
+    _exchange_topk,
     _pad0,
     _shard_topk_merge,
     _shmap,
@@ -282,10 +283,7 @@ class _ShardCtx:
                             w, g, k, sigma=sigma, scalars=scalars, active=active_loc,
                             tile=program.tile,
                         )
-                        gi = loc + jnp.asarray(d * Ks, jnp.int32)
-                        cv = jax.lax.all_gather(vals, axis_name, tiled=True)
-                        ci = jax.lax.all_gather(gi, axis_name, tiled=True)
-                        idx = merge_topk_candidates(cv, ci, k)
+                        idx = _exchange_topk(vals, loc + jnp.asarray(d * Ks, jnp.int32), k, axis_name)
                 else:
                     with stage("round.allocate"):
                         p, capped = masked_prob_alloc(
@@ -300,8 +298,9 @@ class _ShardCtx:
                             mask, ties = topk_mask(scores, k)
                         return None, p, capped, sigma, mask, ties
                     k_sel = jax.random.fold_in(k1, d) if D > 1 else k1
-                    scores = jnp.where(active_loc > 0, perturbed_scores(k_sel, p), -jnp.inf)
-                    idx = _shard_topk_merge(scores, k, axis_name)
+                    with stage("round.sample"):
+                        scores = jnp.where(active_loc > 0, perturbed_scores(k_sel, p), -jnp.inf)
+                        idx = _shard_topk_merge(scores, k, axis_name)
             elif scheme == "random":
                 idx = random_select(k1, K, k)
             elif scheme == "fedcs":

@@ -55,6 +55,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.selection.sampling import local_topk_candidates, merge_topk_candidates, perturbed_scores
 from repro.kernels.bisect_tiles import bisect_block_sums
+from repro.obs.trace import stage
 
 __all__ = [
     "prob_alloc_sharded",
@@ -67,17 +68,26 @@ __all__ = [
     "sharded_selection_sim",
 ]
 
+
+def _exchange_topk(vals: jax.Array, gidx: jax.Array, k: int, axis_name: str):
+    """The candidate exchange: an all-gather of every shard's ``(k,)``
+    candidate scores and global indices into ``(D*k,)`` pairs, and the exact
+    merge (``repro.core.selection.sampling.merge_topk_candidates``), under
+    the ``round.exchange`` stage.  Returns the replicated ``(k,)`` global
+    top-k indices."""
+    with stage("round.exchange"):
+        cv = jax.lax.all_gather(vals, axis_name, tiled=True)
+        ci = jax.lax.all_gather(gidx, axis_name, tiled=True)
+        return merge_topk_candidates(cv, ci, k)
+
+
 def _shard_topk_merge(scores_loc: jax.Array, k: int, axis_name: str):
     """The one distributed top-k step every sharded selection shares: this
     shard's ``lax.top_k(k)`` candidates (global indices via the shard
-    offset), an all-gather of the ``(D, k)`` pairs, and the exact merge
-    (``repro.core.selection.sampling.merge_topk_candidates``).  Returns the
-    replicated ``(k,)`` global top-k indices."""
+    offset), then the candidate exchange (``_exchange_topk``)."""
     Ks = scores_loc.shape[0]
     v, gi = local_topk_candidates(scores_loc, k, jax.lax.axis_index(axis_name) * Ks)
-    cv = jax.lax.all_gather(v, axis_name, tiled=True)
-    ci = jax.lax.all_gather(gi, axis_name, tiled=True)
-    return merge_topk_candidates(cv, ci, k)
+    return _exchange_topk(v, gi, k, axis_name)
 
 
 def _shmap(f, mesh, in_specs, out_specs):

@@ -268,6 +268,47 @@ class TestShardedScanD8:
         assert np.array_equal(np.concatenate(succ), np.asarray(successes))
         np.testing.assert_array_equal(np.asarray(st.sel_counts), np.asarray(state.sel_counts))
 
+    def test_exchange_is_scoped_and_changes_no_bit(self, mesh8, monkeypatch):
+        # the staged round's candidate exchange (the all-gathers of the
+        # (D*k,) candidates and their merge) is named round.exchange inside
+        # round.sample; the scopes are metadata: the round built without
+        # them (as before they were added) gives the same masks and
+        # log-weights bit for bit
+        import contextlib
+        import re
+
+        from repro.configs.base import FLConfig
+        from repro.engine import round_program, sharded
+        from repro.engine.round_program import RoundProgram
+
+        K, k, T = 4096, 32, 24
+        fl = FLConfig(K=K, k=k, rounds=10**9, scheme="e3cs", sampler="plackett_luce", allocator="bisect",
+                      quota="const", quota_frac=0.5, eta=0.5)
+        xs = jnp.asarray(pack_trace(np.random.default_rng(5).binomial(1, 0.6, (T, K)).astype(np.float32)))
+
+        def run():
+            program = RoundProgram.from_config(fl, mesh=mesh8, override="packed", block=4)
+            runner, state0 = program.build_runner(outputs="full", scan_length=T)
+            compiled = jax.jit(runner).lower(state0, jax.random.PRNGKey(3), xs).compile()
+            state, masks, *_ = compiled(state0, jax.random.PRNGKey(3), xs)
+            return compiled.as_text(), np.asarray(masks), np.asarray(state.e3cs.logw)
+
+        hlo, masks, logw = run()
+        gathers = [re.search(r'op_name="([^"]*)"', line).group(1)
+                   for line in hlo.splitlines() if re.search(r"= \S+ all-gather(-start)?\(", line)]
+        assert len(gathers) >= 2, "the exchange gathers candidate scores and indices"
+        assert all("round.sample/round.exchange/" in op for op in gathers), gathers
+        assert (masks.sum(1) == k).all()
+
+        stage = round_program.stage
+        monkeypatch.setattr(sharded, "stage", lambda name: contextlib.nullcontext())
+        monkeypatch.setattr(round_program, "stage",
+                            lambda name: contextlib.nullcontext() if name == "round.sample" else stage(name))
+        hlo0, masks0, logw0 = run()
+        assert "round.exchange" not in hlo0
+        assert np.array_equal(masks, masks0)
+        assert np.array_equal(logw.view(np.uint32), logw0.view(np.uint32))
+
 
 class TestBisectTilesKernel:
     @pytest.mark.parametrize("K,tile", [(64, 128), (1000, 256), (8193, 1024)])
