@@ -48,9 +48,11 @@ of the ``SelectionServer`` that serves the engine): ``engine.copy_in`` (host row
 the host-to-device copy), ``engine.launch`` (the asynchronous dispatch of
 the compiled step), ``engine.wait`` (the host blocked on the device: the
 NaN/inf guard), ``engine.copy_out`` (device-to-host reads of the results)
-and ``engine.cohort`` (host post-processing), and three counters:
+and ``engine.cohort`` (host post-processing), and the counters
 ``engine.ticks`` (jobs stepped), ``engine.syncs`` (blocking device-to-host
-reads) and ``engine.copy_bytes`` (bytes copied between host and device).
+reads) and ``engine.copy_bytes`` (bytes copied between host and device);
+a sync :class:`ShardedEngine` also counts ``engine.packed_rows`` (ticks whose
+feedback crossed to the chip as packed bits).
 """
 from __future__ import annotations
 
@@ -148,6 +150,7 @@ class SlotEngine:
     """
 
     kind = "slots"
+    packed_feedback = False  # ticks take int32 (K,) lag rows
 
     def __init__(
         self,
@@ -413,7 +416,13 @@ class ShardedEngine:
     """Each admitted job is one K-sharded ``RoundProgram`` stepped a round
     per tick (see module doc).  ``staleness=S`` serves sharded-async rounds
     with the ``(S, K/D)`` rings carried per job; ``feedback`` picks the
-    selector policy (``"deadline"`` or ``"late_credit"``)."""
+    selector policy (``"deadline"`` or ``"late_credit"``).
+
+    Sync jobs (``staleness=0``) run the program's packed observe source:
+    feedback crosses to the chip as ``(ceil(K/8),)`` uint8 success bits in
+    little-endian order (``protocol.feedback_packed``) and the
+    ``unpack_bits`` kernel expands it there.  Their per-client state is
+    padded to a multiple of 8·D clients (inactive past K)."""
 
     kind = "sharded"
 
@@ -439,6 +448,11 @@ class ShardedEngine:
         self.faults = None  # chaos hook (repro.serve.faults.FaultPlan) or None
         self.spans = SPANS  # a SelectionServer hands its own recorder
 
+    @property
+    def packed_feedback(self) -> bool:
+        """Whether ticks take packed success-bit rows (sync jobs)."""
+        return self.staleness == 0
+
     def _runner(self, spec: JobSpec):
         from repro.configs.base import FLConfig
         from repro.engine.round_program import RoundProgram
@@ -453,7 +467,8 @@ class ShardedEngine:
             staleness_rounds=self.staleness, staleness_alpha=self.alpha,
         )
         program = RoundProgram.from_config(
-            fl, mesh=self.mesh, override="dense", feedback=self.feedback, block=self.block
+            fl, mesh=self.mesh, override="packed" if self.packed_feedback else "dense",
+            feedback=self.feedback, block=self.block,
         )
         run, state0 = program.build_runner(outputs="full", carry_key=True, scan_length=1)
         self._runners[geom] = (run, state0, program)
@@ -482,9 +497,27 @@ class ShardedEngine:
         the transport's retry cache compares request rounds against)."""
         return int(self.jobs[uid]["t"])
 
+    def _feedback_row(self, uid: int, K: int, row) -> np.ndarray:
+        """A tick's feedback as the row the job's program reads: int32 lag
+        codes ``(K,)`` (async), or packed success bits ``(ceil(K/8),)``
+        uint8 (sync) — a uint8 row is taken as already packed, any other
+        as lag codes and packed here (lag 0 = on time)."""
+        row = np.asarray(row)
+        if self.packed_feedback and row.dtype == np.uint8:
+            row, B = row.reshape(-1), -(-K // 8)
+            if row.shape[0] != B:
+                raise ValueError(f"job {uid}: packed feedback has {row.shape[0]} bytes, K={K} needs {B}")
+            return row
+        row = row.astype(np.int32, copy=False).reshape(-1)
+        if row.shape[0] != K:
+            raise ValueError(f"job {uid}: feedback has {row.shape[0]} entries, K={K}")
+        return np.packbits(row == 0, bitorder="little") if self.packed_feedback else row
+
     def tick(self, items: List[Tuple[int, np.ndarray]]) -> Dict[int, dict]:
         """Advance each job one round (dispatched per job — the K axis is
-        already device-parallel; there is no J axis to batch here)."""
+        already device-parallel; there is no J axis to batch here).  A
+        row is a job's lag codes ``(K,)`` or, sync, its packed success bits
+        (``_feedback_row``)."""
         if self.faults is not None:
             self.faults.on_engine_step()
         spans = self.spans
@@ -494,13 +527,8 @@ class ShardedEngine:
             spec: JobSpec = job["spec"]
             run, _, _ = self._runner(spec)
             with spans.span("engine.copy_in"):
-                row = np.asarray(row, np.int32).reshape(-1)
-                if row.shape[0] != spec.K:
-                    raise ValueError(f"job {uid}: feedback has {row.shape[0]} entries, K={spec.K}")
-                if self.staleness:
-                    xs = jnp.asarray(row, jnp.int32)[None, :]
-                else:
-                    xs = jnp.asarray(row == 0, jnp.float32)[None, :]
+                row = self._feedback_row(uid, spec.K, row)
+                xs = jnp.asarray(row)[None, :]
             with spans.span("engine.launch"):
                 if self.staleness:
                     state, key, rings, masks, lags, ps, sigmas, arrived = run(
@@ -512,6 +540,8 @@ class ShardedEngine:
             spans.add("engine.ticks")
             spans.add("engine.syncs")  # the guard
             spans.add("engine.copy_bytes", xs.nbytes + 1)
+            if self.packed_feedback:
+                spans.add("engine.packed_rows")
             # NaN/inf guard: the runner does not donate, so the old state is
             # intact — refuse the update before assigning anything
             with spans.span("engine.wait"):
@@ -531,9 +561,13 @@ class ShardedEngine:
             spans.add("engine.syncs", len(reads))
             spans.add("engine.copy_bytes", sum(a.nbytes for a in reads))
             with spans.span("engine.cohort"):
-                stale = float(late.sum()) if late is not None else 0.0
                 cohort = np.nonzero(mask > 0)[0]
-                on_time = float((mask * (row == 0)).sum())
+                if self.staleness:
+                    stale = float(late.sum())
+                    on_time = float((mask * (row == 0)).sum())
+                else:  # the cohort's bits, read from the packed row
+                    stale = 0.0
+                    on_time = float(((row[cohort >> 3] >> (cohort & 7)) & 1).sum())
                 results[uid] = {
                     "round": job["t"],
                     "cohort": cohort.tolist(),

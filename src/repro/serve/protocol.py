@@ -22,6 +22,12 @@ Feedback encodings for ``tick`` requests, smallest first:
   ``DEAD_LAG``).
 * ``"x": [..]`` — a plain JSON list: success bits (sync) or lag codes
   (async, ``-1`` = never).  Convenient, ~10x the bytes.
+
+Engines take one of two row forms: ``feedback_lags`` gives int32 ``(K,)``
+lag codes (``SlotEngine``, async ``ShardedEngine``); ``feedback_packed``
+gives a sync ``ShardedEngine`` the ``(ceil(K/8),)`` uint8 row its packed
+observe stage reads (``repro.kernels.unpack_bits``: little-endian bit
+order), so ``"xb"`` reaches the chip without a ``(K,)`` pass on the host.
 """
 from __future__ import annotations
 
@@ -48,12 +54,17 @@ __all__ = [
     "encode_lags",
     "decode_lags",
     "feedback_lags",
+    "feedback_packed",
 ]
 
 MAX_MESSAGE_BYTES = 64 << 20  # one frame; ~6e7 clients as packed bits
 LAG_NEVER = 255  # uint8 wire code for "never completes"
 DEAD_LAG = -1  # engine-side sentinel (== repro.core.volatility.DEAD_LAG)
 _HEADER = struct.Struct("!I")
+# byte -> the byte with its bit order reversed: np.packbits' big-endian
+# order to the little-endian order of repro.kernels.unpack_bits
+_BIT_FLIP = np.packbits(np.unpackbits(np.arange(256, dtype=np.uint8)[:, None], axis=1),
+                        axis=1, bitorder="little").ravel()
 
 
 class ProtocolError(RuntimeError):
@@ -171,3 +182,23 @@ def feedback_lags(req: dict, K: int, staleness: int) -> Optional[np.ndarray]:
         lag = a.astype(np.int32)
         return np.where(lag < 0, DEAD_LAG, lag)
     return None
+
+
+def feedback_packed(req: dict, K: int) -> Optional[np.ndarray]:
+    """A sync ``tick`` request's feedback as ``(ceil(K/8),)`` uint8 packed
+    success bits in little-endian bit order (bit ``j`` of byte ``b`` is
+    client ``8b + j``; ``np.packbits(x, bitorder="little")``).  ``"xb"``
+    is flipped a byte at a time, never unpacked; bytes past ``ceil(K/8)``
+    are ignored and bits past ``K`` are zero.  ``"xl"`` and ``"x"`` go
+    through ``feedback_lags`` (sync: lag 0 is on time).  Returns None when
+    the request carries no feedback field."""
+    if "xb" not in req:
+        lag = feedback_lags(req, K, staleness=0)
+        return None if lag is None else np.packbits(lag == 0, bitorder="little")
+    raw = np.frombuffer(base64.b64decode(req["xb"]), np.uint8)
+    if raw.size * 8 < K:
+        raise ProtocolError(f"packed bits cover {raw.size * 8} clients, need {K}")
+    out = np.take(_BIT_FLIP, raw[: -(-K // 8)])
+    if K % 8:
+        out[-1] &= (1 << (K % 8)) - 1
+    return out

@@ -523,6 +523,7 @@ class SelectionServer:
         now = time.monotonic()
         t_take = time.perf_counter_ns()
         spans = self.spans
+        packed = self.engine.packed_feedback
         live: List[_Item] = []
         items: List[Tuple[int, np.ndarray]] = []
         for item in batch:
@@ -562,15 +563,18 @@ class SelectionServer:
             spec: JobSpec = job["spec"]
             try:
                 with spans.span("serve.decode", rid=item.rid):
-                    lag = protocol.feedback_lags(item.req, spec.K, self.engine.staleness)
+                    if packed:
+                        row = protocol.feedback_packed(item.req, spec.K)
+                    else:
+                        row = protocol.feedback_lags(item.req, spec.K, self.engine.staleness)
             except protocol.ProtocolError as e:
                 item.respond(_err("bad_request", str(e)))
                 continue
-            if lag is None:
+            if row is None:
                 item.respond(_err("bad_request", "tick carries no feedback (x/xb/xl)"))
                 continue
             live.append(item)
-            items.append((uid, lag))
+            items.append((uid, row))
         if not items:
             return
         try:

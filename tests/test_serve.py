@@ -25,6 +25,7 @@ What is pinned here, and why it matters:
   checkpoint + dropped connections on a sharded-async horizon, with every
   cohort bit-identical to a fault-free run.
 """
+import base64
 import json
 import socket
 import struct
@@ -35,6 +36,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.serve import (
     CapacityError,
@@ -104,6 +106,51 @@ def test_protocol_feedback_encodings():
     lag = protocol.feedback_lags(req, 9, staleness=0)
     np.testing.assert_array_equal(lag == 0, bits.astype(bool))
     assert set(np.unique(lag)) <= {0, protocol.DEAD_LAG}
+
+
+@pytest.mark.parametrize("K", [1, 7, 9, 64, 1003])
+def test_feedback_packed_is_little_endian_packbits(K):
+    """The sync decode turns the wire's big-endian ``"xb"`` into the
+    little-endian row ``unpack_bits`` reads, without unpacking."""
+    bits = np.random.default_rng(K).random(K) < 0.6
+    row = protocol.feedback_packed({"xb": protocol.encode_bits(bits)}, K)
+    assert row.dtype == np.uint8 and row.shape == (-(-K // 8),)
+    np.testing.assert_array_equal(row, np.packbits(bits, bitorder="little"))
+
+
+@pytest.mark.parametrize("K", [9, 1003])
+def test_feedback_packed_ignores_surplus_bytes_and_bits(K):
+    """Bytes past ceil(K/8) are ignored and the bits past K come out zero,
+    whatever the wire carried there."""
+    raw = np.full(-(-K // 8) + 3, 0xFF, np.uint8)
+    row = protocol.feedback_packed({"xb": base64.b64encode(raw.tobytes()).decode()}, K)
+    np.testing.assert_array_equal(row, np.packbits(np.ones(K, bool), bitorder="little"))
+    np.testing.assert_array_equal(np.unpackbits(row, bitorder="little")[K:], 0)
+
+
+def test_feedback_packed_short_payload_is_an_error():
+    short = protocol.encode_bits(np.ones(8))
+    with pytest.raises(protocol.ProtocolError):
+        protocol.feedback_packed({"xb": short}, 9)
+    with pytest.raises(protocol.ProtocolError):
+        protocol.feedback_packed({"x": [1] * 8}, 9)
+    assert protocol.feedback_packed({}, 9) is None
+
+
+@pytest.mark.parametrize("K", [7, 64, 1003])
+def test_feedback_packed_every_encoding_agrees(K):
+    """``"x"``, ``"xb"`` and ``"xl"`` carrying the same round decode to the
+    same packed row, and it marks what ``feedback_lags`` calls on time."""
+    rng = np.random.default_rng(K + 1)
+    bits = rng.random(K) < 0.5
+    lags = np.where(bits, 0, protocol.DEAD_LAG)
+    reqs = [{"xb": protocol.encode_bits(bits)}, {"x": bits.astype(int).tolist()},
+            {"xl": protocol.encode_lags(lags)}]
+    rows = [protocol.feedback_packed(r, K) for r in reqs]
+    for row in rows[1:]:
+        np.testing.assert_array_equal(row, rows[0])
+    lag = protocol.feedback_lags(reqs[0], K, staleness=0)
+    np.testing.assert_array_equal(np.unpackbits(rows[0], count=K, bitorder="little"), lag == 0)
 
 
 # ---------------------------------------------------------------------------
@@ -266,10 +313,108 @@ def test_served_tick_records_every_span_once(kind):
     assert stats["counters"]["engine.ticks"] == 1 and stats["spans_dropped"] == 0
     delta = {k: v - before.get(k, 0) for k, v in spans.counters.items()}
     if kind == "sharded":
-        # float32 row in, float32 mask out, the guard's one byte
-        assert delta == {"engine.ticks": 1, "engine.syncs": 2, "engine.copy_bytes": 8 * K + 1}
+        # packed row in, float32 mask out, the guard's one byte
+        assert delta == {"engine.ticks": 1, "engine.syncs": 2, "engine.copy_bytes": K // 8 + 4 * K + 1,
+                         "engine.packed_rows": 1}
     else:
         assert delta["engine.ticks"] == 1 and delta["engine.syncs"] >= 2
+
+
+# ---------------------------------------------------------------------------
+# ShardedEngine: sync feedback crosses to the chip packed
+# ---------------------------------------------------------------------------
+
+
+def _dense_golden(spec: JobSpec, D: int, bits):
+    """The sync job as a ``RoundProgram(override="dense")`` runner fed
+    float32 bit rows: what ``ShardedEngine`` ran before its rows were
+    packed.  Per tick: (cohort, on_time), then the final logw and t."""
+    from repro.configs.base import FLConfig
+    from repro.engine.round_program import RoundProgram
+    from repro.launch.mesh import make_host_mesh
+
+    fl = FLConfig(K=spec.K, k=spec.k, rounds=spec.rounds, scheme="e3cs", quota=spec.quota,
+                  quota_frac=spec.sigma_frac, eta=spec.eta, allocator="bisect")
+    program = RoundProgram.from_config(fl, mesh=make_host_mesh(D), override="dense", block=4)
+    run, state = program.build_runner(outputs="full", carry_key=True, scan_length=1)
+    key, out = jax.random.PRNGKey(spec.seed), []
+    for b in bits:
+        state, key, masks, *_ = run(state, key, jnp.asarray(b, jnp.float32)[None, :])
+        mask = np.asarray(masks[0][: spec.K])
+        out.append((np.nonzero(mask > 0)[0].tolist(), float((mask * b).sum())))
+    return out, np.asarray(state.e3cs.logw)[: spec.K], int(state.t)
+
+
+@pytest.mark.parametrize("D", [1, 2])
+def test_sharded_sync_packed_rows_match_lag_rows_and_dense_golden(D):
+    """Packed rows, the equivalent int32 lag rows and the dense runner the
+    engine used to build give identical cohorts, on-time counts,
+    log-weights and round counters over 12 ticks."""
+    spec = JobSpec(K=128, k=8, rounds=40, seed=5)
+    rng = np.random.default_rng(D)
+    bits = [rng.random(spec.K) < 0.7 for _ in range(12)]
+    want, want_logw, want_t = _dense_golden(spec, D, bits)
+    feeds = {
+        "packed": [np.packbits(b, bitorder="little") for b in bits],
+        "lags": [np.where(b, 0, protocol.DEAD_LAG).astype(np.int32) for b in bits],
+    }
+    for form, rows in feeds.items():
+        eng = ShardedEngine(D=D)
+        assert eng.packed_feedback
+        u = eng.admit(spec)
+        got = [eng.tick([(u, r)])[u] for r in rows]
+        assert [(g["cohort"], g["on_time"]) for g in got] == want, form
+        assert [g["round"] for g in got] == list(range(12)) and all(g["stale"] == 0.0 for g in got)
+        job = eng.jobs[u]
+        np.testing.assert_array_equal(np.asarray(job["state"].e3cs.logw)[: spec.K], want_logw)
+        assert job["t"] == int(job["state"].t) == want_t == 12
+
+
+def test_sharded_packed_row_of_the_wrong_width_is_refused():
+    eng = ShardedEngine(D=1)
+    u = eng.admit(JobSpec(K=64, k=4, seed=1))
+    with pytest.raises(ValueError, match="bytes"):
+        eng.tick([(u, np.zeros(9, np.uint8))])
+    with pytest.raises(ValueError, match="entries"):
+        eng.tick([(u, np.zeros(63, np.int32))])
+    assert eng.job_round(u) == 0
+
+
+def test_served_xb_and_x_replies_are_equal():
+    """Through the socket, a sync sharded job answers ``"xb"`` ticks exactly
+    as it answers ``"x"`` ticks carrying the same bits."""
+    K, rng = 96, np.random.default_rng(7)
+    bits = [rng.random(K) < 0.6 for _ in range(6)]
+    replies = {}
+    for form in ("bits", "x"):
+        with SelectionServer(ShardedEngine(D=1)) as srv:
+            with ServeClient.connect(srv.address) as c:
+                job = c.admit(K=K, k=6, seed=4, rounds=20)
+                replies[form] = [c.tick(job, **{form: b.astype(int)}) for b in bits]
+    assert replies["bits"] == replies["x"]
+    assert [r["round"] for r in replies["x"]] == list(range(6))
+
+
+@pytest.mark.parametrize("D", [1, 2])
+def test_sharded_sync_checkpoint_roundtrip_at_ragged_K(tmp_path, D):
+    """K=9 pads the packed job to whole shard bytes: a checkpoint of that
+    state restores and continues bit-identically."""
+    spec = JobSpec(K=9, k=2, rounds=30, seed=6)
+    rng = np.random.default_rng(9)
+    feed = [_lags(rng, spec.K, S=0) for _ in range(10)]
+    ref = ShardedEngine(D=D)
+    ur = ref.admit(spec)
+    want = [ref.tick([(ur, f)])[ur] for f in feed]
+    eng = ShardedEngine(D=D)
+    u = eng.admit(spec)
+    for f in feed[:5]:
+        eng.tick([(u, f)])
+    eng2, step = load_server(save_server(str(tmp_path), eng, step=5))
+    assert step == 5 and eng2.job_round(u) == 5
+    got = [eng2.tick([(u, f)])[u] for f in feed[5:]]
+    assert got == want[5:]
+    np.testing.assert_array_equal(np.asarray(eng2.jobs[u]["state"].e3cs.logw),
+                                  np.asarray(ref.jobs[ur]["state"].e3cs.logw))
 
 
 def test_transport_concurrent_clients_batch():

@@ -162,7 +162,7 @@ def test_sharded_fused_round(topo, monkeypatch, sync):
 def test_staged_round_sorts_nothing(topo, monkeypatch, cell):
     """The benchmark cells' staged rounds on one chip: the fleet's dense
     round (K=1e7, packed replay) and the coord's ``ShardedEngine(D=1)``
-    round (K=1e6 on a 1-device mesh, dense rows).  The select stage is the
+    round (K=1e6 on a 1-device mesh, packed rows).  The select stage is the
     threshold select's kernel, and the program holds no sort (``lax.top_k``
     lowers to one over K)."""
     import numpy as np
@@ -183,9 +183,9 @@ def test_staged_round_sorts_nothing(topo, monkeypatch, cell):
         rest = (jax.tree.map(lambda a: _spec(on_chip, (), a.dtype), ROUND_TAPS.init_counters()),
                 _spec(on_chip, (2, K_cell // 8), jnp.uint8))
     else:
-        program = RoundProgram.from_config(fl, mesh=mesh, override="dense", block=4)
+        program = RoundProgram.from_config(fl, mesh=mesh, override="packed", block=4)
         run, state0 = program.build_runner(outputs="full", carry_key=True, scan_length=1)
-        rest = (_spec(on_chip, (1, K_cell)),)
+        rest = (_spec(on_chip, (1, K_cell // 8), jnp.uint8),)
     state = jax.tree.map(lambda a: _spec(on_chip, np.shape(a), a.dtype), state0)
     text = run.lower(state, _spec(on_chip, (2,), jnp.uint32), *rest).compile().as_text()
     assert "tpu_custom_call" in text
